@@ -9,7 +9,10 @@ by the full table rows.  Each simulation table is run twice: the first
 ``us_per_call`` is the steady-state time and the cold/steady/compile split
 is written — together with the frozen-seed serial-baseline comparison for
 ``figs15_17`` and the sweep engine's compile counters — to
-``BENCH_noc.json`` so the perf trajectory is tracked across PRs.
+``BENCH_noc.json`` so the perf trajectory is tracked across PRs.  The
+persistent compilation cache is always on (``repro.compile_cache``: where
+``JAX_COMPILATION_CACHE_DIR`` says, else ``<checkout>/.jax_cache``), and
+its hit/miss counts are recorded too.
 
 Roofline terms for the dry-run cells live in EXPERIMENTS.md (they come
 from launch/dryrun.py, not wall-clock).
@@ -22,56 +25,13 @@ import os
 import sys
 import time
 
-import jax
-
 from benchmarks import analysis_bench, fault_sweep, kernel_micro, \
     noc_tables, serial_baseline
 from benchmarks import trace_replay as trace_replay_mod
+from repro import compile_cache
 from repro.core import sweep
 
 RESULTS: dict = {"tables": {}}
-
-# Persistent-cache hit/miss counters, fed by jax's monitoring events.
-_PCACHE = {"hits": 0, "misses": 0}
-
-
-def _setup_persistent_cache() -> dict | None:
-    """Opt-in JAX persistent compilation cache: set REPRO_COMPILE_CACHE
-    to a directory and repeat runs skip XLA compilation entirely (the
-    in-process jit caches in ``sweep`` only help within one run).
-    Returns the state dict recorded into BENCH_noc.json, or None when
-    the env var is unset."""
-    d = os.environ.get("REPRO_COMPILE_CACHE")
-    if not d:
-        return None
-    # A bad cache dir (unwritable parent, path collides with a file, ...)
-    # must degrade to an uncached run, not kill the benchmark.
-    try:
-        os.makedirs(d, exist_ok=True)
-        probe = os.path.join(d, ".write_probe")
-        with open(probe, "w"):
-            pass
-        os.remove(probe)
-    except OSError as e:
-        print(f"# REPRO_COMPILE_CACHE unusable ({e}); "
-              "continuing without persistent cache", file=sys.stderr)
-        return None
-    jax.config.update("jax_compilation_cache_dir", d)
-    # Benchmark programs compile fast; cache everything regardless of
-    # compile time or artifact size so the hit counters are meaningful.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-
-    from jax._src import monitoring
-
-    def _count(event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            _PCACHE["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            _PCACHE["misses"] += 1
-
-    monitoring.register_event_listener(_count)
-    return {"dir": d, "entries_before": len(os.listdir(d))}
 
 
 def _with_fresh_cache(fn):
@@ -121,7 +81,8 @@ def main() -> None:
                    help="skip the frozen-seed serial baseline comparison")
     args, _ = p.parse_known_args()
     v = not args.terse
-    pcache = _setup_persistent_cache()
+    compile_cache.enable()
+    pcache_before = compile_cache.stats()
 
     sizes = (16, 64) if args.quick else (16, 64, 256)
     scal_sizes = (16, 32, 64, 128) if args.quick \
@@ -205,14 +166,12 @@ def main() -> None:
     if not matched:
         print(f"# no table matches --only {args.only!r}", file=sys.stderr)
 
-    if pcache is not None:
-        pcache.update(entries_after=len(os.listdir(pcache["dir"])),
-                      hits=_PCACHE["hits"], misses=_PCACHE["misses"])
-        RESULTS["compile_cache"]["persistent"] = pcache
-        print(f"# persistent compile cache: {_PCACHE['hits']} hits / "
-              f"{_PCACHE['misses']} misses "
-              f"({pcache['entries_before']} -> {pcache['entries_after']} "
-              f"entries in {pcache['dir']})")
+    pcache = compile_cache.stats()
+    pcache["entries_before"] = pcache_before["entries"]
+    RESULTS["compile_cache"]["persistent"] = pcache
+    print(f"# persistent compile cache: {pcache['hits']} hits / "
+          f"{pcache['misses']} misses ({pcache['entries_before']} -> "
+          f"{pcache['entries']} entries in {pcache['dir']})")
 
     # Quick / partial runs must not clobber the committed full-run record.
     out = "BENCH_noc.json" if not (args.quick or args.only) \

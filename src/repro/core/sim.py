@@ -691,10 +691,15 @@ def _to_result(topo: topo_mod.Topology, cfg: SimConfig,
     )
 
 
-def simulate(topo: topo_mod.Topology, cfg: SimConfig) -> SimResult:
-    """Run one simulation; returns steady-state metrics."""
+def simulate(topo: topo_mod.Topology, cfg: SimConfig,
+             device: Optional[jax.Device] = None) -> SimResult:
+    """Run one simulation; returns steady-state metrics.  ``device``
+    commits the inputs there, so the run compiles for and executes on that
+    device (default: JAX's default device)."""
     geom = build_geometry(topo)
     point = make_point(cfg, topo.n_pes, topo)
+    if device is not None:
+        geom, point = jax.device_put((geom, point), device)
     metrics = _run_single(geom, point, cycles=cfg.cycles, warmup=cfg.warmup,
                           starvation_limit=cfg.starvation_limit,
                           backend=cfg.backend,

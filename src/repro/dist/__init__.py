@@ -16,15 +16,7 @@ Modules:
     compression   — int8 quantization + error feedback, compressed psum
     data_parallel — manual-DP gradient functions (flat / hier / int8 pod hop)
     decode_attn   — sequence-sharded decode attention over a ppermute ring
-
-Importing this package also applies ``compat.ensure()``: a minimal,
-idempotent backfill of newer jax APIs the codebase targets
-(``jax.make_mesh(axis_types=...)``, ``jax.shard_map``,
-``jax.sharding.AxisType``) for the pinned jax in this container.
 """
-from repro.dist import compat as _compat
 
-_compat.ensure()
-
-__all__ = ["compat", "context", "sharding", "collectives", "compression",
+__all__ = ["context", "sharding", "collectives", "compression",
            "data_parallel", "decode_attn"]

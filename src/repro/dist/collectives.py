@@ -19,8 +19,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.dist.compat import axis_size
-
 
 def hierarchical_psum(x, axes: tuple[str, ...] = ("pod", "data")):
     """All-reduce ``x`` over ``axes`` with the hierarchical schedule.
@@ -33,7 +31,7 @@ def hierarchical_psum(x, axes: tuple[str, ...] = ("pod", "data")):
     if len(axes) == 1:
         return jax.lax.psum(x, axes[0])
     outer, inner = axes[0], axes[1:]
-    n_inner = int(np.prod([axis_size(a) for a in inner]))
+    n_inner = int(np.prod([jax.lax.axis_size(a) for a in inner]))
     flat = x.reshape(-1)
     size = flat.shape[0]
     pad = (-size) % n_inner

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat, context
+from repro.dist import context
 from repro.dist import collectives, compression, sharding
 
 SCHEDULES = ("flat", "hier")
@@ -81,9 +81,11 @@ def make_dp_grad_fn(loss_fn: Callable, mesh, *, schedule: str = "flat",
         b = jax.tree.leaves(batch)[0].shape[0]
         entry = sharding.batch_entry(mesh, b)
         batch_specs = jax.tree.map(lambda _: P(entry), batch)
-        mapped = compat.shard_map(shard_fn, mesh,
-                                  in_specs=(P(), batch_specs),
-                                  out_specs=(P(), P()))
+        # check_vma off: the bodies' psum_scatter/ppermute patterns are
+        # not replication-typed.
+        mapped = jax.shard_map(shard_fn, mesh=mesh,
+                               in_specs=(P(), batch_specs),
+                               out_specs=(P(), P()), check_vma=False)
         return mapped(params, batch)
 
     return fn

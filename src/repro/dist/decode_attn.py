@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat, context
+from repro.dist import context
 
 _NEG = -1e30  # finite mask value: keeps the streaming max NaN-free
 
@@ -122,7 +122,7 @@ def seq_sharded_attention(q, k, v, *, causal: bool = True,
                                chunk=chunk, skv=skv, causal=causal,
                                window=window, scale=scale)
 
-    mapped = compat.shard_map(body, mesh,
-                              in_specs=(qspec, kvspec, kvspec, P()),
-                              out_specs=qspec)
+    mapped = jax.shard_map(body, mesh=mesh,
+                           in_specs=(qspec, kvspec, kvspec, P()),
+                           out_specs=qspec, check_vma=False)
     return mapped(q, k, v, off)

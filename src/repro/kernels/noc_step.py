@@ -20,9 +20,10 @@ backend scans it and the Pallas kernel calls it on values read from its
 refs, so the two backends are bit-identical by construction — every
 accumulator is an int32, leaving no reduction-order slack
 (tests/test_noc_kernel.py asserts equality across the full matrix).  Off
-TPU the kernel runs in interpret mode (``interpret=None`` auto-detects),
-which keeps CPU tests and CI green while exercising the exact kernel
-body that Mosaic compiles on TPU.
+TPU the kernel runs in interpret mode (``interpret=None`` auto-detects).
+On TPU, Mosaic does not compile it yet: its gather lowering accepts only
+same-shape 2-D ``take_along_axis``, and ``cycle_step`` is built from
+arbitrary integer gathers (tests/test_tpu_compile.py pins the refusal).
 """
 from __future__ import annotations
 
@@ -367,7 +368,19 @@ def cycle_step(geom, state, cycle, inj, dst, fault_u=None, *, warmup: int,
 
 # ---------------------------------------------------------------------------
 # The fused kernel: the whole cycle loop as one pallas_call.
+#
+# Mosaic tiles the last two dimensions of every ref (8 sublanes x 128
+# lanes), so the kernel's refs are all at least 2-D: each 1-D vector rides
+# as a [1, N] row (read back with ``_vec``), the per-cycle streams are
+# [cycles, 1, N] with the cycle dimension squeezed out of the block, and
+# bool/int16 rows travel as int32.  The route table keeps int16 to halve
+# its VMEM footprint.
 # ---------------------------------------------------------------------------
+def _vec(ref):
+    """[N] value of a [1, N] row ref."""
+    return ref[0]
+
+
 def _noc_step_kernel(*refs, cycles: int, warmup: int, starvation_limit: int,
                      arb_iters: int, diagnostics: bool, trace_mode: bool,
                      fault_mode: bool, strict_barrier: bool, watchdog: int):
@@ -382,7 +395,7 @@ def _noc_step_kernel(*refs, cycles: int, warmup: int, starvation_limit: int,
     k += 10
     faults = None
     if fault_mode:
-        faults = (refs[k][...], refs[k + 1][...], refs[k + 2][...])
+        faults = tuple(_vec(r) for r in refs[k:k + 3])
         k += 3
     if trace_mode:
         ph_dst_ref, ph_flits_ref, ph_total_ref = refs[k:k + 3]
@@ -412,44 +425,45 @@ def _noc_step_kernel(*refs, cycles: int, warmup: int, starvation_limit: int,
             stall_ref[...] = jnp.zeros_like(stall_ref)
 
     geom = GeomArrays(
-        route=route_ref[...], kind=kind_ref[...], prio=prio_ref[...],
-        cap=cap_ref[...], phys=phys_ref[...], is_sink=sink_ref[...],
-        pe_src_link=pe_src_ref[...], inj_pe=inj_pe_ref[...],
+        route=route_ref[...], kind=_vec(kind_ref), prio=_vec(prio_ref),
+        cap=_vec(cap_ref), phys=_vec(phys_ref), is_sink=_vec(sink_ref) != 0,
+        pe_src_link=_vec(pe_src_ref), inj_pe=_vec(inj_pe_ref),
         cand=cand_ref[...], intab=intab_ref[...])
-    state = (qpack_ref[...], qlen_ref[...], wait_ref[...],
-             mscal_ref[...], mkind_ref[...])
+    state = (qpack_ref[...], _vec(qlen_ref), _vec(wait_ref),
+             _vec(mscal_ref), mkind_ref[...])
     trace = None
     if trace_mode:
-        state = state + (phidx_ref[...], sent_ref[...], credit_ref[...],
-                         phdone_ref[...], stall_ref[...])
-        trace = (ph_dst_ref[...], ph_flits_ref[...], ph_total_ref[...])
+        state = state + tuple(_vec(r) for r in (phidx_ref, sent_ref,
+                                                credit_ref, phdone_ref,
+                                                stall_ref))
+        trace = (ph_dst_ref[...], ph_flits_ref[...], _vec(ph_total_ref))
     out = cycle_step(
-        geom, state, cycle, inj_ref[0], dst_ref[0],
-        fu_ref[0] if fault_mode else None, warmup=warmup,
+        geom, state, cycle, _vec(inj_ref) != 0, _vec(dst_ref),
+        _vec(fu_ref) if fault_mode else None, warmup=warmup,
         starvation_limit=starvation_limit, arb_iters=arb_iters,
         trace=trace, faults=faults, strict_barrier=strict_barrier,
         watchdog=watchdog, diagnostics=diagnostics)
     q_pack, q_len, wait, m_scal, m_kind = out[:5]
     qpack_ref[...] = q_pack
-    qlen_ref[...] = q_len
-    wait_ref[...] = wait
-    mscal_ref[...] = m_scal
+    qlen_ref[...] = q_len[None]
+    wait_ref[...] = wait[None]
+    mscal_ref[...] = m_scal[None]
     mkind_ref[...] = m_kind
     if trace_mode:
         ph_idx, sent, credit, ph_done, stall = out[5:]
-        phidx_ref[...] = ph_idx
-        sent_ref[...] = sent
-        credit_ref[...] = credit
-        phdone_ref[...] = ph_done
-        stall_ref[...] = stall
+        phidx_ref[...] = ph_idx[None]
+        sent_ref[...] = sent[None]
+        credit_ref[...] = credit[None]
+        phdone_ref[...] = ph_done[None]
+        stall_ref[...] = stall[None]
 
     @pl.when(cycle == cycles - 1)
     def _fin():
-        qlen_out_ref[...] = q_len
-        mscal_out_ref[...] = m_scal
+        qlen_out_ref[...] = q_len[None]
+        mscal_out_ref[...] = m_scal[None]
         mkind_out_ref[...] = m_kind
         if trace_mode:
-            phdone_out_ref[...] = ph_done
+            phdone_out_ref[...] = ph_done[None]
 
 
 def default_interpret() -> bool:
@@ -490,67 +504,82 @@ def run_fused(geom, inj_s: jax.Array, dst_s: jax.Array, *, cycles: int,
     lp1, p_pes = geom.route.shape
     depth = geom.depth
 
-    def _const(shape):
+    def _block(shape):
         nd = len(shape)
         return pl.BlockSpec(shape, lambda c, _nd=nd: (0,) * _nd)
 
-    row = pl.BlockSpec((1, p_pes), lambda c: (c, 0))
+    def _const(shape):
+        # Grid-invariant inputs are fetched once: one VMEM buffer each.
+        nd = len(shape)
+        return pl.BlockSpec(shape, lambda c, _nd=nd: (0,) * _nd,
+                            pipeline_mode=pl.Buffered(1))
+
+    def _stream(n):
+        # Row c of a [cycles, 1, n] stream at grid step c.
+        return pl.BlockSpec((None, 1, n), lambda c: (c, 0, 0))
+
+    def _row(x):
+        return x.reshape(1, -1)
+
     kernel = functools.partial(
         _noc_step_kernel, cycles=cycles, warmup=warmup,
         starvation_limit=starvation_limit, arb_iters=arb_iters,
         diagnostics=diagnostics, trace_mode=trace is not None,
         fault_mode=faults is not None, strict_barrier=strict_barrier,
         watchdog=watchdog)
-    in_specs = [row, row]
-    operands = [inj_s, dst_s]
+    in_specs = [_stream(p_pes), _stream(p_pes)]
+    operands = [inj_s.astype(jnp.int32)[:, None],
+                dst_s.astype(jnp.int32)[:, None]]
     if faults is not None:
         n_faults = int(faults[0].shape[0])
-        in_specs.append(pl.BlockSpec((1, n_faults), lambda c: (c, 0)))
-        operands.append(fault_u)
+        in_specs.append(_stream(n_faults))
+        operands.append(fault_u[:, None])
     in_specs += [
-        _const((lp1, p_pes)),            # route
-        _const((lp1,)), _const((lp1,)),  # kind, prio
-        _const((lp1,)), _const((lp1,)),  # cap, phys
-        _const((lp1,)),                  # is_sink
-        _const((p_pes,)),                # pe_src_link
-        _const((lp1,)),                  # inj_pe
+        _const((lp1, p_pes)),                  # route
+        _const((1, lp1)), _const((1, lp1)),    # kind, prio
+        _const((1, lp1)), _const((1, lp1)),    # cap, phys
+        _const((1, lp1)),                      # is_sink
+        _const((1, p_pes)),                    # pe_src_link
+        _const((1, lp1)),                      # inj_pe
         _const(tuple(geom.cand.shape)),
         _const(tuple(geom.intab.shape)),
     ]
-    operands += [geom.route, geom.kind, geom.prio, geom.cap,
-                 geom.phys, geom.is_sink, geom.pe_src_link, geom.inj_pe,
+    operands += [geom.route, _row(geom.kind), _row(geom.prio),
+                 _row(geom.cap), _row(geom.phys),
+                 _row(geom.is_sink.astype(jnp.int32)),
+                 _row(geom.pe_src_link), _row(geom.inj_pe),
                  geom.cand, geom.intab]
     if faults is not None:
-        in_specs += [_const((n_faults,))] * 3
-        operands += list(faults)
-    out_specs = [_const((lp1,)), _const((N_SCALARS,)),
-                 _const((N_KIND_ROWS, 8))]
-    out_shape = [jax.ShapeDtypeStruct((lp1,), jnp.int32),
-                 jax.ShapeDtypeStruct((N_SCALARS,), jnp.int32),
+        in_specs += [_const((1, n_faults))] * 3
+        operands += [_row(f) for f in faults]
+    out_specs = [_block((1, lp1)), _block((1, N_SCALARS)),
+                 _block((N_KIND_ROWS, 8))]
+    out_shape = [jax.ShapeDtypeStruct((1, lp1), jnp.int32),
+                 jax.ShapeDtypeStruct((1, N_SCALARS), jnp.int32),
                  jax.ShapeDtypeStruct((N_KIND_ROWS, 8), jnp.int32)]
     scratch_shapes = [
         pltpu.VMEM((lp1, depth), jnp.int32),      # packed queue words
-        pltpu.VMEM((lp1,), jnp.int32),            # queue lengths
-        pltpu.VMEM((lp1,), jnp.int32),            # aging counters
-        pltpu.VMEM((N_SCALARS,), jnp.int32),      # scalar metrics
+        pltpu.VMEM((1, lp1), jnp.int32),          # queue lengths
+        pltpu.VMEM((1, lp1), jnp.int32),          # aging counters
+        pltpu.VMEM((1, N_SCALARS), jnp.int32),    # scalar metrics
         pltpu.VMEM((N_KIND_ROWS, 8), jnp.int32),  # per-kind metrics
     ]
     if trace is not None:
         ph_dst, ph_flits, ph_total = trace
         n_phases = ph_dst.shape[0]
         in_specs += [_const((n_phases, p_pes)), _const((n_phases, p_pes)),
-                     _const((n_phases,))]
-        operands += [ph_dst, ph_flits, ph_total]
-        out_specs.append(_const((n_phases,)))
-        out_shape.append(jax.ShapeDtypeStruct((n_phases,), jnp.int32))
+                     _const((1, n_phases))]
+        operands += [ph_dst, ph_flits, _row(ph_total)]
+        out_specs.append(_block((1, n_phases)))
+        out_shape.append(jax.ShapeDtypeStruct((1, n_phases), jnp.int32))
         scratch_shapes += [
-            pltpu.VMEM((1,), jnp.int32),          # phase cursor
-            pltpu.VMEM((p_pes,), jnp.int32),      # per-PE flits sent
-            pltpu.VMEM((1,), jnp.int32),          # retired-flit credit
-            pltpu.VMEM((n_phases,), jnp.int32),   # completion cycles
-            pltpu.VMEM((1,), jnp.int32),          # stall-watchdog counter
+            pltpu.VMEM((1, 1), jnp.int32),          # phase cursor
+            pltpu.VMEM((1, p_pes), jnp.int32),      # per-PE flits sent
+            pltpu.VMEM((1, 1), jnp.int32),          # retired-flit credit
+            pltpu.VMEM((1, n_phases), jnp.int32),   # completion cycles
+            pltpu.VMEM((1, 1), jnp.int32),          # stall-watchdog counter
         ]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(cycles,),
         in_specs=in_specs,
@@ -559,3 +588,4 @@ def run_fused(geom, inj_s: jax.Array, dst_s: jax.Array, *, cycles: int,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
     )(*operands)
+    return (out[0][0], out[1][0], out[2]) + tuple(o[0] for o in out[3:])

@@ -10,10 +10,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist import compat as _compat
-
-_compat.ensure()  # jax.make_mesh(axis_types=...) on older jax
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)

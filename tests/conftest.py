@@ -1,37 +1,8 @@
-"""Test-suite bootstrap.
-
-* Gates the optional `hypothesis` dependency: when the real package is
-  missing (this container does not ship it and installs are not allowed),
-  a minimal deterministic stub (`tests/_hypothesis_stub.py`) is registered
-  under the same import name so the property-based suites still collect
-  and run with fixed-seed sampled examples.
-* Applies `repro.dist.compat.ensure()` early so seed tests written against
-  the current jax API (`jax.make_mesh(axis_types=...)`, `jax.shard_map`)
-  run on the pinned jax in this container.
-"""
+"""Test-suite bootstrap: puts ``src/`` and the checkout root (for
+``chip_smoke.py``) on the import path."""
 import os
 import sys
-import types
 
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "src"))
-
-try:
-    import hypothesis  # noqa: F401  (real package wins when present)
-except ImportError:
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_stub as _stub
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = _stub.given
-    mod.settings = _stub.settings
-    mod.strategies = types.ModuleType("hypothesis.strategies")
-    for name in ("integers", "booleans", "floats", "sampled_from", "lists",
-                 "tuples"):
-        setattr(mod.strategies, name, getattr(_stub, name))
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = mod.strategies
-
-from repro.dist import compat as _compat  # noqa: E402
-
-_compat.ensure()
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(_ROOT, "src"))
+sys.path.insert(0, _ROOT)
