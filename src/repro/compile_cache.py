@@ -21,23 +21,24 @@ import pathlib
 import jax
 from jax.experimental.compilation_cache import compilation_cache
 
+from repro import obs
+
 DEFAULT_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
-_COUNTS = {"hits": 0, "misses": 0, "compile_s": 0.0}
 _listening = False
 
 
 def _on_event(event: str, **kw) -> None:
     if event == "/jax/compilation_cache/cache_hits":
-        _COUNTS["hits"] += 1
+        obs.add("compile_cache.hits")
     elif event == "/jax/compilation_cache/cache_misses":
-        _COUNTS["misses"] += 1
+        obs.add("compile_cache.misses")
 
 
 def _on_duration(event: str, secs: float, **kw) -> None:
     # XLA compilation, or loading the executable on a persistent-cache hit.
     if event == "/jax/core/compile/backend_compile_duration":
-        _COUNTS["compile_s"] += secs
+        obs.add("compile_cache.compile_s", secs)
 
 
 def enable() -> str:
@@ -69,7 +70,12 @@ def enable() -> str:
 def stats() -> dict:
     """Cache directory, its entry count, and this process's persistent-cache
     hits and misses plus the seconds spent in XLA compilation (or cache
-    loads) since it started."""
+    loads) since it started: a view of ``obs``' ``compile_cache.*``
+    counters."""
     d = jax.config.jax_compilation_cache_dir
     entries = len(os.listdir(d)) if d and os.path.isdir(d) else 0
-    return {"dir": d, "entries": entries, **_COUNTS}
+    c = obs.snapshot()
+    return {"dir": d, "entries": entries,
+            "hits": int(c.get("compile_cache.hits", 0)),
+            "misses": int(c.get("compile_cache.misses", 0)),
+            "compile_s": float(c.get("compile_cache.compile_s", 0.0))}

@@ -26,9 +26,11 @@ so metrics are bit-identical to the legacy string-pattern paths.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 from typing import Iterable, Optional, Sequence, Union
 
+from repro import obs
 from repro.core import analytic, area, power, sim, sweep, traffic
 from repro.core.spec import TopologySpec
 from repro.faults.spec import FaultSpec
@@ -193,22 +195,29 @@ class Experiment:
         return cls.from_dict(json.loads(s))
 
 
+_CALLS = itertools.count(1)
+
+
 def run_experiments(exps: Sequence[Experiment]) -> list["Report"]:
     """Run many experiments, batching aggressively: experiments are
     grouped by topology spec (one geometry upload each; mixed budgets
     group further inside ``sweep.sweep``), compilation for the next
     geometry pipelines behind the current dispatch (``sweep_many``), and
-    results come back in input order."""
-    groups: dict[TopologySpec, list[int]] = {}
-    for i, e in enumerate(exps):
-        groups.setdefault(e.topology, []).append(i)
-    tasks = [(spec_.build(), [exps[i].sim_config() for i in idxs])
-             for spec_, idxs in groups.items()]
-    out: list[Optional[Report]] = [None] * len(exps)
-    for (_, idxs), results in zip(groups.items(), sweep.sweep_many(tasks)):
-        for i, r in zip(idxs, results):
-            out[i] = _report(exps[i], r)
-    return out  # type: ignore[return-value]
+    results come back in input order.  The call is the host span
+    ``repro.run_experiments``; it and every span inside it carry the
+    call's id, ``call=<n>``."""
+    with obs.span("repro.run_experiments", call=next(_CALLS)):
+        groups: dict[TopologySpec, list[int]] = {}
+        for i, e in enumerate(exps):
+            groups.setdefault(e.topology, []).append(i)
+        tasks = [(spec_.build(), [exps[i].sim_config() for i in idxs])
+                 for spec_, idxs in groups.items()]
+        out: list[Optional[Report]] = [None] * len(exps)
+        for (_, idxs), results in zip(groups.items(),
+                                      sweep.sweep_many(tasks)):
+            for i, r in zip(idxs, results):
+                out[i] = _report(exps[i], r)
+        return out  # type: ignore[return-value]
 
 
 # ---------------------------------------------------------------------------
@@ -291,13 +300,14 @@ class Report:
 
 
 def _report(exp: Experiment, r: sim.SimResult) -> Report:
-    activity = power.activity_from_sim(r.flit_hops_per_cycle,
-                                       exp.topology.n_pes)
-    topo = exp.topology.build()
-    return Report(experiment=exp, sim=r,
-                  power=power.power(topo, activity),
-                  area=area.area(topo),
-                  analytic=_bounds(exp.topology))
+    with obs.span("repro.experiment.report"):
+        activity = power.activity_from_sim(r.flit_hops_per_cycle,
+                                           exp.topology.n_pes)
+        topo = exp.topology.build()
+        return Report(experiment=exp, sim=r,
+                      power=power.power(topo, activity),
+                      area=area.area(topo),
+                      analytic=_bounds(exp.topology))
 
 
 def _sim_config_to_dict(cfg: sim.SimConfig) -> dict:

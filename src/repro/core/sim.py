@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import packet as pk
 from repro.core import topology as topo_mod
 from repro.core import traffic
@@ -176,6 +177,11 @@ class SimResult:
     # watchdog fired — the credits the stalled phase never retired.
     reachability: float = 1.0
     stall_unretired: int = 0
+    # Arbitration passes this point needed, summed over every cycle,
+    # warmup included: 1 to ARB_ITERS a cycle.  The same under simulate,
+    # sweep and both backends (a batch's passes run are the sweep's
+    # counter, DESIGN.md §15).
+    arb_passes: int = 0
 
     @property
     def n_phases(self) -> int:
@@ -236,6 +242,7 @@ class SimResult:
             "delivered": self.delivered, "offered": self.offered,
             "dropped": self.dropped, "lost": self.lost,
             "in_flight": self.in_flight,
+            "arb_passes": self.arb_passes,
         }
         if self.phase_done:
             r["n_phases"] = self.n_phases
@@ -316,6 +323,8 @@ class Metrics:
     q_len_by_kind: jax.Array      # [8]
     phase_done: jax.Array         # [n_phases] int32 ([0] when statistical)
     stall_unretired: jax.Array    # credits unretired at watchdog fire
+    arb_passes: jax.Array         # arbitration passes the point needed
+    arb_passes_run: jax.Array     # passes its program ran (batch max)
 
 
 jax.tree_util.register_dataclass(
@@ -323,7 +332,7 @@ jax.tree_util.register_dataclass(
     data_fields=["delivered", "offered", "accepted", "dropped", "lost",
                  "lat_sum", "moved", "in_flight", "wins_by_kind",
                  "stall_next_kind", "q_len_by_kind", "phase_done",
-                 "stall_unretired"],
+                 "stall_unretired", "arb_passes", "arb_passes_run"],
     meta_fields=[])
 
 
@@ -491,12 +500,13 @@ def _structural_cache(topo: topo_mod.Topology) -> dict:
 def build_geometry(topo: topo_mod.Topology) -> Geometry:
     """Device-ready geometry; the route table is re-read every call so
     in-place morphs (``core.morph``) take effect immediately."""
-    c = _structural_cache(topo)
-    route = np.concatenate(
-        [topo.route_table.astype(np.int16),
-         np.full((1, topo.n_pes), -1, np.int16)], axis=0)
+    with obs.span("repro.sim.geometry"):
+        c = _structural_cache(topo)
+        route = jnp.asarray(np.concatenate(
+            [topo.route_table.astype(np.int16),
+             np.full((1, topo.n_pes), -1, np.int16)], axis=0))
     return Geometry(
-        route=jnp.asarray(route),
+        route=route,
         kind=c["kind"], prio=c["prio"], cap=c["cap"], phys=c["phys"],
         is_sink=c["is_sink"], pe_src_link=c["pe_src_link"],
         inj_pe=c["inj_pe"], cand=c["cand"], intab=c["intab"],
@@ -510,7 +520,12 @@ def build_geometry(topo: topo_mod.Topology) -> Geometry:
 def _run_core(geom: Geometry, point: SweepPoint, *, cycles: int, warmup: int,
               starvation_limit: int, arb_iters: int = ARB_ITERS,
               diagnostics: bool = False, backend: str = "xla",
-              strict_barrier: bool = False, watchdog: int = 0) -> Metrics:
+              strict_barrier: bool = False, watchdog: int = 0,
+              batch_axis: str | None = None) -> Metrics:
+    """The whole run of one point: traffic drawn under the named scope
+    ``point.traffic``, then ``cycles`` steps of ``noc_step.cycle_step``.
+    ``batch_axis`` names the vmap axis the run is batched under (XLA
+    backend; ``cycle_step`` counts the passes the batch ran)."""
     L, P = geom.n_links, geom.n_pes
     kinds8 = jnp.arange(8, dtype=jnp.int32)[:, None]  # [8, 1]
     kind_oh = geom.kind[None, :] == kinds8           # [8, L+1] static mask
@@ -518,41 +533,46 @@ def _run_core(geom: Geometry, point: SweepPoint, *, cycles: int, warmup: int,
     # --- traffic pregeneration (cycle-invariant work hoisted out of the
     # scan: peer indices are static, all randomness is drawn in five large
     # vectorized calls instead of per-cycle splits) ----------------------
-    pes = jnp.arange(P, dtype=jnp.int32)
-    ring_base = pes - pes % pk.PES_PER_RINGLET
-    pos_ring = pes % pk.PES_PER_RINGLET
-    blk_base = pes - pes % pk.PES_PER_BLOCK
-    pos_blk = pes % pk.PES_PER_BLOCK
-
     # Fault entries ride the point as traced data; their [F] shape is the
     # static "fault shape".  Healthy points keep the historical 5-way key
     # split, so healthy random streams are bit-identical with or without
     # the fault machinery compiled in.
     n_faults = int(point.fault_links.shape[0])
-    key = jax.random.PRNGKey(point.seed)
-    if n_faults:
-        k_inj, k_dst, k_loc, k_ring, k_blk, k_flt = jax.random.split(key, 6)
-        fu_s = jax.random.uniform(k_flt, (cycles, n_faults))
-        faults = (point.fault_links, point.fault_drop_p, point.fault_onset)
-    else:
-        k_inj, k_dst, k_loc, k_ring, k_blk = jax.random.split(key, 5)
-        fu_s, faults = None, None
-    inj_s = jax.random.bernoulli(k_inj, point.inj_rate, (cycles, P))
-    off_s = jax.random.randint(k_dst, (cycles, P), 1, P, dtype=jnp.int32)
-    u_s = jax.random.uniform(k_loc, (cycles, P))
-    ring_s = jax.random.randint(k_ring, (cycles, P), 1, pk.PES_PER_RINGLET,
-                                dtype=jnp.int32)
-    blk_s = jax.random.randint(k_blk, (cycles, P), 1, pk.PES_PER_BLOCK,
-                               dtype=jnp.int32)
-    base_s = (pes[None, :] + off_s) % P  # uniform over everyone else
-    base_s = jnp.where(point.use_perm,
-                       jnp.broadcast_to(point.perm_dst, (cycles, P)), base_s)
-    ring_peer = ring_base + (pos_ring[None, :] + ring_s) % pk.PES_PER_RINGLET
-    blk_peer = blk_base + (pos_blk[None, :] + blk_s) % pk.PES_PER_BLOCK
-    dst_s = jnp.where(
-        u_s < point.loc_ring, ring_peer,
-        jnp.where(u_s < point.loc_ring + point.loc_block, blk_peer,
-                  base_s)).astype(jnp.int16)
+    with jax.named_scope("point.traffic"):
+        pes = jnp.arange(P, dtype=jnp.int32)
+        ring_base = pes - pes % pk.PES_PER_RINGLET
+        pos_ring = pes % pk.PES_PER_RINGLET
+        blk_base = pes - pes % pk.PES_PER_BLOCK
+        pos_blk = pes % pk.PES_PER_BLOCK
+        key = jax.random.PRNGKey(point.seed)
+        if n_faults:
+            k_inj, k_dst, k_loc, k_ring, k_blk, k_flt = jax.random.split(
+                key, 6)
+            fu_s = jax.random.uniform(k_flt, (cycles, n_faults))
+            faults = (point.fault_links, point.fault_drop_p,
+                      point.fault_onset)
+        else:
+            k_inj, k_dst, k_loc, k_ring, k_blk = jax.random.split(key, 5)
+            fu_s, faults = None, None
+        inj_s = jax.random.bernoulli(k_inj, point.inj_rate, (cycles, P))
+        off_s = jax.random.randint(k_dst, (cycles, P), 1, P,
+                                   dtype=jnp.int32)
+        u_s = jax.random.uniform(k_loc, (cycles, P))
+        ring_s = jax.random.randint(k_ring, (cycles, P), 1,
+                                    pk.PES_PER_RINGLET, dtype=jnp.int32)
+        blk_s = jax.random.randint(k_blk, (cycles, P), 1, pk.PES_PER_BLOCK,
+                                   dtype=jnp.int32)
+        base_s = (pes[None, :] + off_s) % P  # uniform over everyone else
+        base_s = jnp.where(point.use_perm,
+                           jnp.broadcast_to(point.perm_dst, (cycles, P)),
+                           base_s)
+        ring_peer = (ring_base
+                     + (pos_ring[None, :] + ring_s) % pk.PES_PER_RINGLET)
+        blk_peer = blk_base + (pos_blk[None, :] + blk_s) % pk.PES_PER_BLOCK
+        dst_s = jnp.where(
+            u_s < point.loc_ring, ring_peer,
+            jnp.where(u_s < point.loc_ring + point.loc_block, blk_peer,
+                      base_s)).astype(jnp.int16)
 
     # Queue payload: one packed int32 word per slot, ``born << 11 | dst+1``
     # (n_pes <= 1024 so dst+1 < 2048; empty slot = 0 -> dst -1).  One array
@@ -594,7 +614,8 @@ def _run_core(geom: Geometry, point: SweepPoint, *, cycles: int, warmup: int,
                 geom, carry, cycle, inj, dst, fault_u=fu, warmup=warmup,
                 starvation_limit=starvation_limit, arb_iters=arb_iters,
                 trace=trace, faults=faults, strict_barrier=strict_barrier,
-                watchdog=watchdog, diagnostics=diagnostics), None
+                watchdog=watchdog, diagnostics=diagnostics,
+                batch_axis=batch_axis), None
 
         carry0 = noc_step.initial_state(L, geom.depth, n_pes=P,
                                         n_phases=n_phases)
@@ -621,7 +642,9 @@ def _run_core(geom: Geometry, point: SweepPoint, *, cycles: int, warmup: int,
         q_len_by_kind=jnp.sum(jnp.where(kind_oh, ql[None, :], 0), axis=1,
                               dtype=jnp.int32),
         phase_done=ph_done,
-        stall_unretired=m_scal[noc_step.STALL_CREDIT])
+        stall_unretired=m_scal[noc_step.STALL_CREDIT],
+        arb_passes=m_scal[noc_step.ARB_PASSES],
+        arb_passes_run=m_scal[noc_step.ARB_PASSES_RUN])
 
 
 _run_single = jax.jit(
@@ -657,9 +680,10 @@ def _fault_reachability(topo: topo_mod.Topology,
     key = (id(topo), topo.name, faults)
     hit = _REACH_CACHE.get(key)
     if hit is None:
-        dead = faults.dead_queue_mask(topo)
-        hit = (topo.reachable_frac if not dead.any()
-               else topo_mod.reachable_fraction(topo, dead))
+        with obs.span("repro.sim.reachability"):
+            dead = faults.dead_queue_mask(topo)
+            hit = (topo.reachable_frac if not dead.any()
+                   else topo_mod.reachable_fraction(topo, dead))
         if len(_REACH_CACHE) > 512:
             _REACH_CACHE.clear()
         _REACH_CACHE[key] = hit
@@ -688,6 +712,7 @@ def _to_result(topo: topo_mod.Topology, cfg: SimConfig,
         phase_done=tuple(int(d) for d in np.asarray(m.phase_done)),
         reachability=_fault_reachability(topo, cfg.faults),
         stall_unretired=int(m.stall_unretired),
+        arb_passes=int(m.arb_passes),
     )
 
 

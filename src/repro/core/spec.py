@@ -20,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 import json
 
+from repro import obs
 from repro.core import morph as morph_mod
 from repro.core import packet as pk
 from repro.core import topology as topo_mod
@@ -154,7 +155,8 @@ class TopologySpec:
         read-only; use ``build_fresh()`` to mutate (e.g. live morphing)."""
         t = _BUILD_CACHE.get(self)
         if t is None:
-            t = _BUILD_CACHE[self] = self.build_fresh()
+            with obs.span("repro.topology.build"):
+                t = _BUILD_CACHE[self] = self.build_fresh()
         return t
 
     @staticmethod

@@ -24,9 +24,17 @@ always come back in input order.
 
 ``compile_stats()`` exposes the jit cache sizes so benchmarks can assert
 the one-compile-per-geometry property (logged into BENCH_noc.json).
+
+Each sweep times its stages as ``repro.obs`` host spans
+(``repro.sweep.prepare``, ``.lower``, ``.compile``, ``.wait``,
+``.to_result``; every span of one group carries ``group=<k>``), and
+counts the arbitration passes its dispatches ran and needed
+(``sweep.arb_passes_run``, ``sweep.arb_passes_needed``) beside the
+point-cycles they simulated (``sweep.point_cycles``).
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import functools
 import threading
@@ -36,9 +44,13 @@ from typing import Iterable, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core import sim
 from repro.core import topology as topo_mod
 from repro.core import traffic
+
+
+_AXIS = "points"   # the vmap axis of a batch
 
 
 @functools.partial(
@@ -52,22 +64,24 @@ def _run_batch(geom: sim.Geometry, points: sim.SweepPoint, *, cycles: int,
     """vmap of the simulator core over a stacked SweepPoint batch; the
     geometry is broadcast (in_axes=None) so it is uploaded once.  Both
     backends vmap — the fused pallas kernel batches its traffic streams
-    against the broadcast geometry."""
+    against the broadcast geometry.  The XLA scan learns the vmap axis,
+    so that it counts the arbitration passes the batch ran."""
     run = functools.partial(sim._run_core, cycles=cycles, warmup=warmup,
                             starvation_limit=starvation_limit,
                             backend=backend, arb_iters=arb_iters,
-                            strict_barrier=strict_barrier, watchdog=watchdog)
-    return jax.vmap(run, in_axes=(None, 0))(geom, points)
+                            strict_barrier=strict_barrier, watchdog=watchdog,
+                            batch_axis=_AXIS if backend == "xla" else None)
+    return jax.vmap(run, in_axes=(None, 0), axis_name=_AXIS)(geom, points)
 
 
 # AOT executable cache.  jit's own cache would work, but holding the
 # compiled objects ourselves lets ``precompile`` build them from worker
 # threads (XLA compilation releases the GIL, so compiles for different
 # geometries overlap each other and any python-side work) and gives the
-# benchmarks an exact compile counter to log.
+# benchmarks an exact compile counter to log (``sweep.batch_xla_compiles``
+# in ``repro.obs``).
 _AOT: dict[tuple, object] = {}
 _AOT_LOCK = threading.Lock()
-_XLA_COMPILES = 0
 
 
 def _static_key(geom: sim.Geometry, batch: int, trace_shape: tuple,
@@ -84,7 +98,6 @@ def _executable(geom: sim.Geometry, points: sim.SweepPoint, cycles: int,
                 warmup: int, starv: int, backend: str = "xla",
                 strict_barrier: bool = False, watchdog: int = 0,
                 arb_iters: int = sim.ARB_ITERS):
-    global _XLA_COMPILES
     key = _static_key(geom, points.seed.shape[0],
                       tuple(points.ph_dst.shape),
                       tuple(points.fault_links.shape), cycles, warmup, starv,
@@ -92,17 +105,22 @@ def _executable(geom: sim.Geometry, points: sim.SweepPoint, cycles: int,
     with _AOT_LOCK:
         exe = _AOT.get(key)
     if exe is None:
-        exe = _run_batch.lower(
-            geom, points, cycles=cycles, warmup=warmup,
-            starvation_limit=starv, backend=backend,
-            strict_barrier=strict_barrier, watchdog=watchdog,
-            arb_iters=arb_iters).compile()
+        with obs.span("repro.sweep.lower"):
+            lowered = _run_batch.lower(
+                geom, points, cycles=cycles, warmup=warmup,
+                starvation_limit=starv, backend=backend,
+                strict_barrier=strict_barrier, watchdog=watchdog,
+                arb_iters=arb_iters)
+        # XLA compilation, or the executable's load from the persistent
+        # compile cache.
+        with obs.span("repro.sweep.compile"):
+            exe = lowered.compile()
         with _AOT_LOCK:
             if key in _AOT:          # lost a compile race: keep the winner
                 exe = _AOT[key]      # (counter stays exact either way)
             else:
                 _AOT[key] = exe
-                _XLA_COMPILES += 1
+                obs.add("sweep.batch_xla_compiles")
     return exe
 
 
@@ -120,29 +138,36 @@ _N_EXE_STATICS = 6
 
 def _grouped(topo: topo_mod.Topology, cfgs: Sequence[sim.SimConfig]):
     """(geometry, [(static key, config indexes, stacked points), ...])."""
-    geom = sim.build_geometry(topo)
-    groups: dict[tuple, list[int]] = {}
-    for i, c in enumerate(cfgs):
-        # The trace phase count and the lowered fault count are array
-        # *shapes*, so points can only stack (and share an executable)
-        # with equal counts; statistical points all have
-        # n_trace_phases == 0, healthy points n_faults == 0, and fault
-        # lowering pads to bucket sizes so nearby fault counts coincide.
-        n_phases = traffic.resolve(c.pattern).n_trace_phases
-        n_faults = c.faults.n_lowered(topo) if c.faults else 0
-        groups.setdefault((c.cycles, c.warmup, c.starvation_limit,
-                           c.backend, c.strict_barrier, c.watchdog,
-                           n_phases, n_faults), []).append(i)
-    return geom, [(key[:_N_EXE_STATICS], idxs,
-                   _stack_points([cfgs[i] for i in idxs], topo))
-                  for key, idxs in groups.items()]
+    with obs.span("repro.sweep.prepare"):
+        geom = sim.build_geometry(topo)
+        groups: dict[tuple, list[int]] = {}
+        for i, c in enumerate(cfgs):
+            # The trace phase count and the lowered fault count are array
+            # *shapes*, so points can only stack (and share an executable)
+            # with equal counts; statistical points all have
+            # n_trace_phases == 0, healthy points n_faults == 0, and fault
+            # lowering pads to bucket sizes so nearby fault counts
+            # coincide.
+            n_phases = traffic.resolve(c.pattern).n_trace_phases
+            n_faults = c.faults.n_lowered(topo) if c.faults else 0
+            groups.setdefault((c.cycles, c.warmup, c.starvation_limit,
+                               c.backend, c.strict_barrier, c.watchdog,
+                               n_phases, n_faults), []).append(i)
+        return geom, [(key[:_N_EXE_STATICS], idxs,
+                       _stack_points([cfgs[i] for i in idxs], topo))
+                      for key, idxs in groups.items()]
 
 
 def _dispatch(topo, cfgs, geom, idxs, points, exe, out):
-    metrics = jax.tree.map(np.asarray, exe(geom, points))
-    for b, i in enumerate(idxs):
-        m_i = jax.tree.map(lambda x: x[b], metrics)
-        out[i] = sim._to_result(topo, cfgs[i], m_i)
+    with obs.span("repro.sweep.wait"):
+        metrics = jax.tree.map(np.asarray, exe(geom, points))
+    with obs.span("repro.sweep.to_result"):
+        for b, i in enumerate(idxs):
+            m_i = jax.tree.map(lambda x: x[b], metrics)
+            out[i] = sim._to_result(topo, cfgs[i], m_i)
+    obs.add("sweep.arb_passes_run", int(metrics.arb_passes_run.sum()))
+    obs.add("sweep.arb_passes_needed", int(metrics.arb_passes.sum()))
+    obs.add("sweep.point_cycles", cfgs[idxs[0]].cycles * len(idxs))
 
 
 def sweep(topo: topo_mod.Topology,
@@ -166,9 +191,10 @@ def sweep(topo: topo_mod.Topology,
         return []
     geom, groups = _grouped(topo, cfgs)
     out: list[sim.SimResult | None] = [None] * len(cfgs)
-    for key, idxs, points in groups:
-        exe = _executable(geom, points, *key)
-        _dispatch(topo, cfgs, geom, idxs, points, exe, out)
+    for k, (key, idxs, points) in enumerate(groups):
+        with obs.tag(group=k):
+            exe = _executable(geom, points, *key)
+            _dispatch(topo, cfgs, geom, idxs, points, exe, out)
     return out  # type: ignore[return-value]
 
 
@@ -181,9 +207,10 @@ def precompile(tasks: Sequence[tuple[topo_mod.Topology,
     jobs = []
     for topo, cfgs in tasks:
         geom, groups = _grouped(topo, cfgs)
-        jobs.extend((geom, points, *key) for key, _, points in groups)
+        jobs.extend(_in_group(k, _executable, geom, points, *key)
+                    for k, (key, _, points) in enumerate(groups))
     with ThreadPoolExecutor(max_workers=workers) as ex:
-        list(ex.map(lambda j: _executable(*j), jobs))
+        list(ex.map(lambda job: job(), jobs))
 
 
 def sweep_many(tasks: Sequence[tuple[topo_mod.Topology,
@@ -195,16 +222,30 @@ def sweep_many(tasks: Sequence[tuple[topo_mod.Topology,
     dispatch streams overlap instead of serializing."""
     prepared = [(topo, cfgs, *_grouped(topo, cfgs)) for topo, cfgs in tasks]
     with ThreadPoolExecutor(max_workers=1) as ex:
-        futs = [[ex.submit(_executable, geom, points, *key)
-                 for key, _, points in groups]
+        futs = [[ex.submit(_in_group(k, _executable, geom, points, *key))
+                 for k, (key, _, points) in enumerate(groups)]
                 for _, _, geom, groups in prepared]
         results = []
         for (topo, cfgs, geom, groups), group_futs in zip(prepared, futs):
             out: list[sim.SimResult | None] = [None] * len(cfgs)
-            for (_, idxs, points), fut in zip(groups, group_futs):
-                _dispatch(topo, cfgs, geom, idxs, points, fut.result(), out)
+            for k, ((_, idxs, points), fut) in enumerate(zip(groups,
+                                                              group_futs)):
+                with obs.tag(group=k):
+                    _dispatch(topo, cfgs, geom, idxs, points, fut.result(),
+                              out)
             results.append(out)
     return results  # type: ignore[return-value]
+
+
+def _in_group(k, fn, *args):
+    """``fn(*args)`` as a job for another thread, its spans tagged
+    ``group=k`` and carrying the ids of the caller's spans."""
+    ctx = contextvars.copy_context()
+
+    def job():
+        with obs.tag(group=k):
+            return fn(*args)
+    return functools.partial(ctx.run, job)
 
 
 def grid(inj_rates: Iterable[float] = (0.25,),
@@ -260,20 +301,21 @@ def sweep_grid(topo: topo_mod.Topology, verify: bool = False,
 
 def compile_stats() -> dict:
     """Compile counters, for the benchmark's one-compile-per-geometry
-    accounting in BENCH_noc.json."""
+    accounting in BENCH_noc.json (``batch_xla_compiles`` is a view of
+    ``repro.obs``' ``sweep.batch_xla_compiles``)."""
     return {
         "batch_executables": len(_AOT),
-        "batch_xla_compiles": int(_XLA_COMPILES),
+        "batch_xla_compiles": int(obs.snapshot().get(
+            "sweep.batch_xla_compiles", 0)),
         "single_cache_entries": sim.compile_cache_size(),
     }
 
 
 def reset_caches() -> None:
-    """Drop every compiled executable and zero the compile counters (both
-    the batch AOT cache and ``sim``'s single-point cache), so tests can
-    assert compile counts from a clean slate."""
-    global _XLA_COMPILES
+    """Drop every compiled executable and zero the sweep's counters and
+    spans in ``repro.obs`` (and ``sim``'s single-point cache), so tests
+    can assert compile counts from a clean slate."""
     with _AOT_LOCK:
         _AOT.clear()
-        _XLA_COMPILES = 0
+        obs.reset("sweep.", "repro.sweep.")
     sim.clear_compile_cache()

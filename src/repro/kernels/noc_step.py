@@ -39,13 +39,15 @@ from jax.experimental.pallas import tpu as pltpu
 # Flat metric-accumulator layout shared by both simulator backends
 # (``core.sim`` reassembles its Metrics pytree from these): slot names of
 # the [N_SCALARS] int32 vector, then the rows of the [N_KIND_ROWS, 8]
-# per-queue-kind table.  The vector is padded to 8 lanes; KIND_QLEN is
-# written from the final queue lengths after the run, not per cycle.
-# STALL_CREDIT records the credits the stall watchdog found unretired
-# when it terminated a trace phase (0 otherwise).
+# per-queue-kind table.  KIND_QLEN is written from the final queue
+# lengths after the run, not per cycle.  STALL_CREDIT records the credits
+# the stall watchdog found unretired when it terminated a trace phase (0
+# otherwise).  ARB_PASSES sums the arbitration passes the point needed,
+# 1 to ``arb_iters`` a cycle, warmup included; ARB_PASSES_RUN sums the
+# passes its program ran: the batch's most under a vmap axis.
 (DELIVERED, OFFERED, ACCEPTED, DROPPED, LOST, LAT_SUM, MOVED,
- STALL_CREDIT) = range(8)
-N_SCALARS = 8
+ STALL_CREDIT, ARB_PASSES, ARB_PASSES_RUN) = range(10)
+N_SCALARS = 10
 KIND_WINS, KIND_STALLS, KIND_QLEN = range(3)
 N_KIND_ROWS = 3
 
@@ -94,13 +96,18 @@ def initial_state(n_links: int, depth: int, *, n_pes: int = 0,
 def cycle_step(geom, state, cycle, inj, dst, fault_u=None, *, warmup: int,
                starvation_limit: int, arb_iters: int, trace=None,
                faults=None, strict_barrier: bool = False, watchdog: int = 0,
-               diagnostics: bool = False):
+               diagnostics: bool = False, batch_axis: str | None = None):
     """One simulator cycle (route -> arbitrate -> move -> inject -> count).
 
     Pure function of VMEM-sized values; see ``core.sim``'s module docstring
     for the model and the scatter-free layout.  ``inj`` is the [P] bool
     injection row and ``dst`` the [P] int16 destination row for this cycle
     (pregenerated by ``sim._run_core``).  Returns the updated state tuple.
+
+    Each stage runs under one flat ``jax.named_scope`` (``cycle.phase``,
+    ``cycle.route``, ``cycle.arbitrate``, ``cycle.fault``, ``cycle.move``,
+    ``cycle.inject``, ``cycle.count``), so a profiler trace can sum device
+    time by stage.  Scopes are op metadata only: they change no op.
 
     ``trace`` switches on phase-gated replay (DESIGN.md §12): a static
     triple of ``(ph_dst [n_phases, P], ph_flits [n_phases, P],
@@ -131,6 +138,12 @@ def cycle_step(geom, state, cycle, inj, dst, fault_u=None, *, warmup: int,
     ``ph_done[phase] = -2 - cycle`` plus the unretired credit in the
     ``STALL_CREDIT`` metric slot, and aborts the replay — a per-phase
     diagnostic instead of spinning to budget exhaustion.
+
+    ``batch_axis`` names the vmap axis the step runs under, if any.  The
+    arbitration loop then runs until the batch's slowest point converges,
+    and ``ARB_PASSES_RUN`` adds that pass count (a ``pmax`` over the axis)
+    where ``ARB_PASSES`` adds the point's own.  Without an axis both slots
+    add the point's own count.
     """
     if trace is None:
         q_pack, q_len, wait, m_scal, m_kind = state
@@ -139,191 +152,208 @@ def cycle_step(geom, state, cycle, inj, dst, fault_u=None, *, warmup: int,
          ph_idx, sent, credit, ph_done, stall) = state
         ph_dst, ph_flits, ph_total = trace
         n_phases = ph_dst.shape[0]
-        cur = jnp.clip(ph_idx[0], 0, n_phases - 1)
-        active = ph_idx[0] < n_phases
-        cur_dst = jax.lax.dynamic_slice_in_dim(ph_dst, cur, 1, 0)[0]
-        cur_flits = jax.lax.dynamic_slice_in_dim(ph_flits, cur, 1, 0)[0]
-        # The Bernoulli row throttles bandwidth (inj_rate=1.0 -> inject as
-        # fast as back-pressure allows); the phase gate does the rest.
-        inj = inj & active & (cur_flits - sent > 0)
-        dst = cur_dst
+        with jax.named_scope("cycle.phase"):
+            cur = jnp.clip(ph_idx[0], 0, n_phases - 1)
+            active = ph_idx[0] < n_phases
+            cur_dst = jax.lax.dynamic_slice_in_dim(ph_dst, cur, 1, 0)[0]
+            cur_flits = jax.lax.dynamic_slice_in_dim(ph_flits, cur, 1, 0)[0]
+            # The Bernoulli row throttles bandwidth (inj_rate=1.0 -> inject
+            # as fast as back-pressure allows); the phase gate does the rest.
+            inj = inj & active & (cur_flits - sent > 0)
+            dst = cur_dst
     lp1, p_pes = geom.route.shape
     n_links = lp1 - 1
     depth = q_pack.shape[1]
     np1 = geom.cand.shape[0]
-    link_ids = jnp.arange(lp1, dtype=jnp.int32)
     pow2 = 1 << int(np.ceil(np.log2(lp1)))
-    row_ids = link_ids[:, None]                        # [L+1, 1]
-    p_ids = jnp.arange(np1, dtype=jnp.int32)[:, None]  # [NP1, 1]
-    col_k = jnp.arange(depth, dtype=jnp.int32)[None, :]
-    measure = cycle >= warmup
 
     # --- 1. routing: next link for every queue head ----------------------
-    head_pack = q_pack[:, 0]
-    head_dst = (head_pack & 2047) - 1
-    head_born = head_pack >> 11
-    valid = q_len > 0
-    nxt = jnp.take_along_axis(
-        geom.route, jnp.clip(head_dst, 0, p_pes - 1)[:, None],
-        axis=1)[:, 0].astype(jnp.int32)
-    nxt = jnp.where(valid, nxt, -1)
-    nxt_c = jnp.clip(nxt, 0, n_links)
-    nxt_phys = geom.phys[nxt_c]
+    with jax.named_scope("cycle.route"):
+        head_pack = q_pack[:, 0]
+        head_dst = (head_pack & 2047) - 1
+        head_born = head_pack >> 11
+        valid = q_len > 0
+        nxt = jnp.take_along_axis(
+            geom.route, jnp.clip(head_dst, 0, p_pes - 1)[:, None],
+            axis=1)[:, 0].astype(jnp.int32)
+        nxt = jnp.where(valid, nxt, -1)
+        nxt_c = jnp.clip(nxt, 0, n_links)
+        nxt_phys = geom.phys[nxt_c]
 
-    # Switched-off routes (INVALID) drop the flit — paper §5.1.
-    drop_route = valid & (nxt < 0)
+        # Switched-off routes (INVALID) drop the flit — paper §5.1.
+        drop_route = valid & (nxt < 0)
 
     # --- 2. arbitration over each output physical channel ----------------
     # One grant per physical channel per cycle; weighted round-robin
     # (§4.2): in-ring traffic leads by a small static margin; waiting
     # inputs age upward so no port starves.
-    contend = valid & (nxt >= 0)
-    eff_prio = geom.prio * 2 + jnp.minimum(wait, starvation_limit)
-    rot = (link_ids + cycle) & (pow2 - 1)     # unique RR tiebreak
-    score = eff_prio * pow2 + rot             # globally unique
+    with jax.named_scope("cycle.arbitrate"):
+        link_ids = jnp.arange(lp1, dtype=jnp.int32)
+        p_ids = jnp.arange(np1, dtype=jnp.int32)[:, None]  # [NP1, 1]
+        contend = valid & (nxt >= 0)
+        eff_prio = geom.prio * 2 + jnp.minimum(wait, starvation_limit)
+        rot = (link_ids + cycle) & (pow2 - 1)     # unique RR tiebreak
+        score = eff_prio * pow2 + rot             # globally unique
 
-    # Fixpoint-invariant gathers: candidate scores, candidate->channel
-    # match, and target occupancy/capacity change per cycle, not per
-    # re-arbitration pass — in the fused kernel they stay in VMEM for the
-    # whole while_loop.
-    cand_score = jnp.where(nxt_phys[geom.cand] == p_ids,
-                           score[geom.cand], -1)   # [NP1, Fc]
-    ql_t = q_len[nxt_c]
-    cap_t = geom.cap[nxt_c]
+        # Fixpoint-invariant gathers: candidate scores, candidate->channel
+        # match, and target occupancy/capacity change per cycle, not per
+        # re-arbitration pass — in the fused kernel they stay in VMEM for
+        # the whole while_loop.
+        cand_score = jnp.where(nxt_phys[geom.cand] == p_ids,
+                               score[geom.cand], -1)   # [NP1, Fc]
+        ql_t = q_len[nxt_c]
+        cap_t = geom.cap[nxt_c]
 
-    def select(active):
-        # Scatter-free argmax per output channel: mask each channel's
-        # structural candidates to the active ones, row-max, then winners
-        # are the queues matching their channel's best (unique) score.
-        best = jnp.max(jnp.where(active[geom.cand], cand_score, -1),
-                       axis=1)
-        return active & (score == best[nxt_phys])
+        def select(active):
+            # Scatter-free argmax per output channel: mask each channel's
+            # structural candidates to the active ones, row-max, then
+            # winners are the queues matching their channel's best
+            # (unique) score.
+            best = jnp.max(jnp.where(active[geom.cand], cand_score, -1),
+                           axis=1)
+            return active & (score == best[nxt_phys])
 
-    def feasible(w):
-        # A grant into a full queue is only feasible if that queue's own
-        # head departs this cycle (lockstep / slotted-ring semantics).
-        return (ql_t - w[nxt_c].astype(jnp.int32)) < cap_t
+        def feasible(w):
+            # A grant into a full queue is only feasible if that queue's
+            # own head departs this cycle (lockstep / slotted-ring
+            # semantics).
+            return (ql_t - w[nxt_c].astype(jnp.int32)) < cap_t
 
-    # Grant-and-re-arbitrate fixpoint with early exit; residue past the
-    # iteration cap is counted (not moved) so conservation stays exact.
-    w0 = select(contend)
-    feas0 = feasible(w0)
+        # Grant-and-re-arbitrate fixpoint with early exit; residue past the
+        # iteration cap is counted (not moved) so conservation stays
+        # exact.  The pass counter is 1 when the first selection is
+        # already feasible.
+        w0 = select(contend)
+        feas0 = feasible(w0)
 
-    def arb_cond(s):
-        return s[3] & (s[4] < arb_iters)
+        def arb_cond(s):
+            return s[3] & (s[4] < arb_iters)
 
-    def arb_body(s):
-        active, w, feas_w, _, i = s
-        active = active & (~w | feas_w)
-        w = select(active)
-        feas_w = feasible(w)
-        return (active, w, feas_w, jnp.any(w & ~feas_w), i + 1)
+        def arb_body(s):
+            active, w, feas_w, _, i = s
+            active = active & (~w | feas_w)
+            w = select(active)
+            feas_w = feasible(w)
+            return (active, w, feas_w, jnp.any(w & ~feas_w), i + 1)
 
-    _, winner, feas_w, _, _ = jax.lax.while_loop(
-        arb_cond, arb_body,
-        (contend, w0, feas0, jnp.any(w0 & ~feas0), jnp.int32(1)))
-    residue = winner & ~feas_w
-    winner = winner & ~residue
+        _, winner, feas_w, _, passes = jax.lax.while_loop(
+            arb_cond, arb_body,
+            (contend, w0, feas0, jnp.any(w0 & ~feas0), jnp.int32(1)))
+        residue = winner & ~feas_w
+        winner = winner & ~residue
 
-    deq = winner | drop_route
-    sink = geom.is_sink[nxt_c]
     # Fault injection: a granted flit crossing a faulty wire is dropped on
     # the wire (it leaves its source queue but never arrives).  faulty_now
     # is a scatter-free [F] x [L+1] compare collapsed over entries.
+    fault_drop = None
     if faults is not None:
-        f_links, f_drop_p, f_onset = faults
-        f_act = (fault_u < f_drop_p) & (cycle >= f_onset)        # [F]
-        faulty_now = jnp.any((f_links[:, None] == link_ids[None, :])
-                             & f_act[:, None], axis=0)           # [L+1]
-        fault_drop = winner & faulty_now[nxt_c]
-        send = winner & ~sink & ~fault_drop
-    else:
-        fault_drop = None
-        send = winner & ~sink
+        with jax.named_scope("cycle.fault"):
+            f_links, f_drop_p, f_onset = faults
+            f_act = (fault_u < f_drop_p) & (cycle >= f_onset)        # [F]
+            faulty_now = jnp.any((f_links[:, None] == link_ids[None, :])
+                                 & f_act[:, None], axis=0)           # [L+1]
+            fault_drop = winner & faulty_now[nxt_c]
 
     # --- 3. apply moves ---------------------------------------------------
-    q_pack = jnp.where(
-        deq[:, None],
-        jnp.concatenate([q_pack[:, 1:],
-                         jnp.zeros((lp1, 1), jnp.int32)], 1), q_pack)
-    q_len = q_len - deq.astype(jnp.int32)
+    with jax.named_scope("cycle.move"):
+        deq = winner | drop_route
+        sink = geom.is_sink[nxt_c]
+        send = winner & ~sink
+        if fault_drop is not None:
+            send = send & ~fault_drop
+        q_pack = jnp.where(
+            deq[:, None],
+            jnp.concatenate([q_pack[:, 1:],
+                             jnp.zeros((lp1, 1), jnp.int32)], 1), q_pack)
+        q_len = q_len - deq.astype(jnp.int32)
 
-    # Scatter-free enqueue: invert the move map through the structural
-    # fan-in table — each queue row finds the (unique) sender targeting
-    # it, then writes its tail slot with a one-hot column mask.
-    inc = send[geom.intab] & (nxt_c[geom.intab] == row_ids)
-    src_q = jnp.max(jnp.where(inc, geom.intab, -1), axis=1)
-    has_in = src_q >= 0
-    src_qc = jnp.clip(src_q, 0, n_links)
-    # Exactness guard: a residue removal can leave a grant whose target is
-    # still full; such moves become counted drops rather than corrupting
-    # queue state (kept 0 by the fixpoint in practice).
-    lost_enq_row = has_in & (q_len >= geom.cap)
-    enq_row = has_in & ~lost_enq_row
-
-    deliver = (winner & sink if fault_drop is None
-               else winner & sink & ~fault_drop)
-    delivered_c = jnp.sum(deliver.astype(jnp.int32))
-    lat_c = jnp.sum(jnp.where(deliver, cycle - head_born, 0))
-    moved_c = jnp.sum(winner.astype(jnp.int32))
-    wait = jnp.where(valid & ~deq, wait + 1, 0)
+        # Scatter-free enqueue: invert the move map through the structural
+        # fan-in table — each queue row finds the (unique) sender targeting
+        # it, then writes its tail slot with a one-hot column mask.
+        row_ids = link_ids[:, None]                        # [L+1, 1]
+        inc = send[geom.intab] & (nxt_c[geom.intab] == row_ids)
+        src_q = jnp.max(jnp.where(inc, geom.intab, -1), axis=1)
+        has_in = src_q >= 0
+        src_qc = jnp.clip(src_q, 0, n_links)
+        # Exactness guard: a residue removal can leave a grant whose target
+        # is still full; such moves become counted drops rather than
+        # corrupting queue state (kept 0 by the fixpoint in practice).
+        lost_enq_row = has_in & (q_len >= geom.cap)
+        enq_row = has_in & ~lost_enq_row
+        wait = jnp.where(valid & ~deq, wait + 1, 0)
 
     # --- 4. injection -----------------------------------------------------
     # Nothing ever routes *into* a PE_SRC queue, so enqueue and injection
     # touch disjoint rows and share one tail-write pass.
-    room = q_len[geom.pe_src_link] < geom.cap[geom.pe_src_link]
-    acc = inj & room
-    pe_of_row = geom.inj_pe
-    pec = jnp.clip(pe_of_row, 0, p_pes - 1)
-    acc_row = (pe_of_row >= 0) & acc[pec]
+    with jax.named_scope("cycle.inject"):
+        col_k = jnp.arange(depth, dtype=jnp.int32)[None, :]
+        room = q_len[geom.pe_src_link] < geom.cap[geom.pe_src_link]
+        acc = inj & room
+        pe_of_row = geom.inj_pe
+        pec = jnp.clip(pe_of_row, 0, p_pes - 1)
+        acc_row = (pe_of_row >= 0) & acc[pec]
 
-    put = enq_row | acc_row
-    tail = put[:, None] & (col_k == jnp.clip(q_len, 0, depth - 1)[:, None])
-    inj_pack = (cycle << 11) | (dst[pec].astype(jnp.int32) + 1)
-    val = jnp.where(enq_row, head_pack[src_qc], inj_pack)
-    q_pack = jnp.where(tail, val[:, None], q_pack)
-    q_len = q_len + put.astype(jnp.int32)
+        put = enq_row | acc_row
+        tail = put[:, None] & (col_k
+                               == jnp.clip(q_len, 0, depth - 1)[:, None])
+        inj_pack = (cycle << 11) | (dst[pec].astype(jnp.int32) + 1)
+        val = jnp.where(enq_row, head_pack[src_qc], inj_pack)
+        q_pack = jnp.where(tail, val[:, None], q_pack)
+        q_len = q_len + put.astype(jnp.int32)
 
-    # --- 5. metric accumulation (int32, warmup-gated; `lost` ungated) ----
-    g = measure.astype(jnp.int32)
-    lost_c = jnp.sum(lost_enq_row.astype(jnp.int32))
-    acc_c = jnp.sum(acc.astype(jnp.int32))
-    fault_drop_c = (jnp.sum(fault_drop.astype(jnp.int32))
-                    if fault_drop is not None else 0)
-    hard_drop_c = (jnp.sum(drop_route.astype(jnp.int32)) + lost_c
-                   + fault_drop_c)
-    if trace is None:
-        offered_c = jnp.sum(inj.astype(jnp.int32))
-        dropped_c = jnp.sum((inj & ~room).astype(jnp.int32)) + hard_drop_c
-    else:
-        # Trace semantics: a blocked injection retries next cycle (the
-        # flit is workload, not a Bernoulli draw that evaporates), so
-        # offered := accepted and back-pressure is not a drop.  This
-        # keeps conservation exact: offered == delivered + dropped +
-        # in_flight at every cycle.
-        offered_c = acc_c
-        dropped_c = hard_drop_c
-    m_scal = m_scal + jnp.stack([
-        g * delivered_c,
-        g * offered_c,
-        g * acc_c,
-        g * dropped_c,
-        lost_c + jnp.sum(residue.astype(jnp.int32)),
-        g * lat_c,
-        g * moved_c,
-        jnp.int32(0),
-    ])
-    if diagnostics:
-        kinds8 = jnp.arange(8, dtype=jnp.int32)[:, None]
-        kind_oh = geom.kind[None, :] == kinds8
-        stalled = contend & ~winner
-        stall_kind = geom.kind[nxt_c]
-        wins = g * jnp.sum(kind_oh & winner[None, :], axis=1,
-                           dtype=jnp.int32)
-        stalls = g * jnp.sum((stall_kind[None, :] == kinds8)
-                             & stalled[None, :], axis=1, dtype=jnp.int32)
-        m_kind = m_kind + jnp.stack(
-            [wins, stalls, jnp.zeros((8,), jnp.int32)])
+    # --- 5. metric accumulation (int32, warmup-gated; `lost` and the
+    # arbitration passes ungated) ------------------------------------------
+    with jax.named_scope("cycle.count"):
+        g = (cycle >= warmup).astype(jnp.int32)
+        deliver = winner & sink
+        if fault_drop is not None:
+            deliver = deliver & ~fault_drop
+        delivered_c = jnp.sum(deliver.astype(jnp.int32))
+        lat_c = jnp.sum(jnp.where(deliver, cycle - head_born, 0))
+        moved_c = jnp.sum(winner.astype(jnp.int32))
+        lost_c = jnp.sum(lost_enq_row.astype(jnp.int32))
+        acc_c = jnp.sum(acc.astype(jnp.int32))
+        fault_drop_c = (jnp.sum(fault_drop.astype(jnp.int32))
+                        if fault_drop is not None else 0)
+        hard_drop_c = (jnp.sum(drop_route.astype(jnp.int32)) + lost_c
+                       + fault_drop_c)
+        if trace is None:
+            offered_c = jnp.sum(inj.astype(jnp.int32))
+            dropped_c = (jnp.sum((inj & ~room).astype(jnp.int32))
+                         + hard_drop_c)
+        else:
+            # Trace semantics: a blocked injection retries next cycle (the
+            # flit is workload, not a Bernoulli draw that evaporates), so
+            # offered := accepted and back-pressure is not a drop.  This
+            # keeps conservation exact: offered == delivered + dropped +
+            # in_flight at every cycle.
+            offered_c = acc_c
+            dropped_c = hard_drop_c
+        passes_run = (passes if batch_axis is None
+                      else jax.lax.pmax(passes, batch_axis))
+        m_scal = m_scal + jnp.stack([
+            g * delivered_c,
+            g * offered_c,
+            g * acc_c,
+            g * dropped_c,
+            lost_c + jnp.sum(residue.astype(jnp.int32)),
+            g * lat_c,
+            g * moved_c,
+            jnp.int32(0),
+            passes,
+            passes_run,
+        ])
+        if diagnostics:
+            kinds8 = jnp.arange(8, dtype=jnp.int32)[:, None]
+            kind_oh = geom.kind[None, :] == kinds8
+            stalled = contend & ~winner
+            stall_kind = geom.kind[nxt_c]
+            wins = g * jnp.sum(kind_oh & winner[None, :], axis=1,
+                               dtype=jnp.int32)
+            stalls = g * jnp.sum((stall_kind[None, :] == kinds8)
+                                 & stalled[None, :], axis=1, dtype=jnp.int32)
+            m_kind = m_kind + jnp.stack(
+                [wins, stalls, jnp.zeros((8,), jnp.int32)])
     if trace is None:
         return q_pack, q_len, wait, m_scal, m_kind
 
@@ -336,32 +366,35 @@ def cycle_step(geom, state, cycle, inj, dst, fault_u=None, *, warmup: int,
     # Under strict_barrier only *deliveries* retire credit (faithful
     # collective semantics: a flit dropped on a dead link leaves the
     # barrier waiting forever — the stall watchdog's job to report).
-    sent = sent + acc.astype(jnp.int32)
-    retired_c = delivered_c if strict_barrier else delivered_c + hard_drop_c
-    credit = credit + retired_c
-    cur_total = jax.lax.dynamic_slice_in_dim(ph_total, cur, 1, 0)[0]
-    done_now = active & (credit[0] >= cur_total)
-    ph_arange = jnp.arange(n_phases, dtype=jnp.int32)
-    ph_done = jnp.where(done_now & (ph_arange == cur), cycle, ph_done)
-    ph_idx = ph_idx + done_now.astype(jnp.int32)
-    sent = jnp.where(done_now, 0, sent)
-    credit = jnp.where(done_now, 0, credit)
-    if watchdog:
-        # Progress = the active phase retired credit, accepted an
-        # injection, or moved a flit (congestion is not a stall; a phase
-        # with nothing in flight and nothing left to retire is).
-        progress = (retired_c > 0) | (acc_c > 0) | (moved_c > 0)
-        stall = jnp.where(active & ~done_now & ~progress, stall + 1,
-                          jnp.zeros_like(stall))
-        fire = active & ~done_now & (stall[0] >= watchdog)
-        # Per-phase diagnostic: the stalled phase records -2 - cycle, the
-        # unretired credit lands in the STALL_CREDIT slot, and the cursor
-        # jumps past the end, aborting the replay.
-        ph_done = jnp.where(fire & (ph_arange == cur), -2 - cycle, ph_done)
-        m_scal = m_scal + jnp.where(
-            jnp.arange(N_SCALARS, dtype=jnp.int32) == STALL_CREDIT,
-            fire.astype(jnp.int32) * (cur_total - credit[0]), 0)
-        ph_idx = jnp.where(fire, n_phases, ph_idx)
+    with jax.named_scope("cycle.phase"):
+        sent = sent + acc.astype(jnp.int32)
+        retired_c = (delivered_c if strict_barrier
+                     else delivered_c + hard_drop_c)
+        credit = credit + retired_c
+        cur_total = jax.lax.dynamic_slice_in_dim(ph_total, cur, 1, 0)[0]
+        done_now = active & (credit[0] >= cur_total)
+        ph_arange = jnp.arange(n_phases, dtype=jnp.int32)
+        ph_done = jnp.where(done_now & (ph_arange == cur), cycle, ph_done)
+        ph_idx = ph_idx + done_now.astype(jnp.int32)
+        sent = jnp.where(done_now, 0, sent)
+        credit = jnp.where(done_now, 0, credit)
+        if watchdog:
+            # Progress = the active phase retired credit, accepted an
+            # injection, or moved a flit (congestion is not a stall; a
+            # phase with nothing in flight and nothing left to retire is).
+            progress = (retired_c > 0) | (acc_c > 0) | (moved_c > 0)
+            stall = jnp.where(active & ~done_now & ~progress, stall + 1,
+                              jnp.zeros_like(stall))
+            fire = active & ~done_now & (stall[0] >= watchdog)
+            # Per-phase diagnostic: the stalled phase records -2 - cycle,
+            # the unretired credit lands in the STALL_CREDIT slot, and the
+            # cursor jumps past the end, aborting the replay.
+            ph_done = jnp.where(fire & (ph_arange == cur), -2 - cycle,
+                                ph_done)
+            m_scal = m_scal + jnp.where(
+                jnp.arange(N_SCALARS, dtype=jnp.int32) == STALL_CREDIT,
+                fire.astype(jnp.int32) * (cur_total - credit[0]), 0)
+            ph_idx = jnp.where(fire, n_phases, ph_idx)
     return (q_pack, q_len, wait, m_scal, m_kind,
             ph_idx, sent, credit, ph_done, stall)
 
@@ -486,7 +519,10 @@ def run_fused(geom, inj_s: jax.Array, dst_s: jax.Array, *, cycles: int,
     m_scal [N_SCALARS], m_kind [N_KIND_ROWS, 8])`` int32 accumulators —
     ``core.sim`` turns them into its Metrics pytree.  vmap-compatible:
     batched traffic streams against a broadcast geometry is exactly how
-    ``core.sweep`` runs whole grids through one compilation.
+    ``core.sweep`` runs whole grids through one compilation.  Batched
+    points run one after another, each with its own arbitration loop, so
+    the kernel's ``ARB_PASSES_RUN`` equals its ``ARB_PASSES``: the passes
+    run are the passes needed.
 
     ``trace`` (the ``cycle_step`` triple) switches on phase-gated replay:
     the phase tables join the resident VMEM set, the barrier state rides
