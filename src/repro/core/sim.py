@@ -405,6 +405,15 @@ class Geometry:
     node (routes are node-local, an invariant morphing preserves), so they
     are supersets of any route table's live edges and stay valid across
     morphs.  Runtime masks (`nxt == target`) select the live subset.
+
+    ``outtab`` is the structural fan-out table, the same invariant seen
+    from the sender: q's next queue always leaves q's destination node, so
+    row q lists every queue leaving ``dst[q]`` (dead queues included: a
+    route may still name them).  ``outphys``/``outcap`` are ``phys`` and
+    ``cap`` read through it.  A batched step looks up "the value at q's
+    target" as a static-index gather over these rows plus a one-hot
+    reduction, instead of a gather whose index differs per point
+    (``kernels.noc_step.cycle_step``, ``arb_fanout``).
     """
     route: jax.Array      # [L+1, P] int16 (refreshed per call: morph-aware)
     kind: jax.Array       # [L+1] int32
@@ -416,6 +425,9 @@ class Geometry:
     inj_pe: jax.Array     # [L+1] int32: PE injecting into this row, or -1
     cand: jax.Array       # [n_phys+1, Fc] int32 queue ids (pad = L)
     intab: jax.Array      # [L+1, Fi] int32 queue ids (pad = L)
+    outtab: jax.Array     # [L+1, Fo] int32 queue ids (pad = L)
+    outphys: jax.Array    # [L+1, Fo] int32: phys[outtab]
+    outcap: jax.Array     # [L+1, Fo] int32: cap[outtab]
     n_links: int
     n_phys: int
     n_pes: int
@@ -426,7 +438,8 @@ class Geometry:
 jax.tree_util.register_dataclass(
     Geometry,
     data_fields=["route", "kind", "prio", "cap", "phys", "is_sink",
-                 "pe_src_link", "inj_pe", "cand", "intab"],
+                 "pe_src_link", "inj_pe", "cand", "intab", "outtab",
+                 "outphys", "outcap"],
     meta_fields=["n_links", "n_phys", "n_pes", "depth", "cap_total"])
 
 
@@ -471,25 +484,41 @@ def _structural_cache(topo: topo_mod.Topology) -> dict:
             b = buckets[src[q]]
             cand[phys[q], :len(b)] = b
 
+    # Fan-out table: the queues leaving q's destination node.  Unlike the
+    # candidate tables it keeps dead queues, since a route may name one.
+    leaving: list[list[int]] = [[] for _ in range(n_nodes)]
+    for q in range(L):
+        if src[q] >= 0:
+            leaving[src[q]].append(q)
+    fo = max((len(b) for b in leaving), default=1) or 1
+    outtab = np.full((L + 1, fo), L, np.int32)
+    for q in range(L):
+        if dst[q] >= 0:
+            b = leaving[dst[q]]
+            outtab[q, :len(b)] = b
+
     inj_pe = np.full(L + 1, -1, np.int32)
     inj_pe[topo.pe_src_link] = np.arange(P, dtype=np.int32)
 
+    phys_d = np.concatenate([phys.astype(np.int32), [topo.n_phys]])
+    cap_d = np.concatenate([topo.link_cap.astype(np.int32), [1 << 30]])
     finite = topo.link_cap < (1 << 29)
     cache = dict(
         kind=jnp.asarray(np.concatenate([topo.link_kind.astype(np.int32),
                                          [0]])),
         prio=jnp.asarray(np.concatenate([topo.link_prio.astype(np.int32),
                                          [0]])),
-        cap=jnp.asarray(np.concatenate([topo.link_cap.astype(np.int32),
-                                        [1 << 30]])),
-        phys=jnp.asarray(np.concatenate([phys.astype(np.int32),
-                                         [topo.n_phys]])),
+        cap=jnp.asarray(cap_d),
+        phys=jnp.asarray(phys_d),
         is_sink=jnp.asarray(np.concatenate([topo.is_sink,
                                             [False]])),
         pe_src_link=jnp.asarray(topo.pe_src_link.astype(np.int32)),
         inj_pe=jnp.asarray(inj_pe),
         cand=jnp.asarray(cand),
         intab=jnp.asarray(intab),
+        outtab=jnp.asarray(outtab),
+        outphys=jnp.asarray(phys_d[outtab]),
+        outcap=jnp.asarray(cap_d[outtab]),
         depth=int(topo.link_cap[finite].max()),
         cap_total=int(topo.link_cap[finite].sum()),
     )
@@ -510,6 +539,7 @@ def build_geometry(topo: topo_mod.Topology) -> Geometry:
         kind=c["kind"], prio=c["prio"], cap=c["cap"], phys=c["phys"],
         is_sink=c["is_sink"], pe_src_link=c["pe_src_link"],
         inj_pe=c["inj_pe"], cand=c["cand"], intab=c["intab"],
+        outtab=c["outtab"], outphys=c["outphys"], outcap=c["outcap"],
         n_links=topo.n_links, n_phys=topo.n_phys, n_pes=topo.n_pes,
         depth=c["depth"], cap_total=c["cap_total"])
 
@@ -521,11 +551,14 @@ def _run_core(geom: Geometry, point: SweepPoint, *, cycles: int, warmup: int,
               starvation_limit: int, arb_iters: int = ARB_ITERS,
               diagnostics: bool = False, backend: str = "xla",
               strict_barrier: bool = False, watchdog: int = 0,
-              batch_axis: str | None = None) -> Metrics:
+              batch_axis: str | None = None,
+              arb_fanout: bool = False) -> Metrics:
     """The whole run of one point: traffic drawn under the named scope
     ``point.traffic``, then ``cycles`` steps of ``noc_step.cycle_step``.
     ``batch_axis`` names the vmap axis the run is batched under (XLA
-    backend; ``cycle_step`` counts the passes the batch ran)."""
+    backend; ``cycle_step`` counts the passes the batch ran);
+    ``arb_fanout`` picks the step's fan-out lookups (XLA backend, chosen
+    by ``core.sweep`` from the batch size; the results are identical)."""
     L, P = geom.n_links, geom.n_pes
     kinds8 = jnp.arange(8, dtype=jnp.int32)[:, None]  # [8, 1]
     kind_oh = geom.kind[None, :] == kinds8           # [8, L+1] static mask
@@ -615,7 +648,7 @@ def _run_core(geom: Geometry, point: SweepPoint, *, cycles: int, warmup: int,
                 starvation_limit=starvation_limit, arb_iters=arb_iters,
                 trace=trace, faults=faults, strict_barrier=strict_barrier,
                 watchdog=watchdog, diagnostics=diagnostics,
-                batch_axis=batch_axis), None
+                batch_axis=batch_axis, arb_fanout=arb_fanout), None
 
         carry0 = noc_step.initial_state(L, geom.depth, n_pes=P,
                                         n_phases=n_phases)

@@ -9,7 +9,8 @@ field (``core.sim``), so a whole grid ``jax.vmap``s through a single
 compiled program and returns all results from one device execution.
 
 Compile-cache key (DESIGN.md §4): array *shapes* only — (n_links, n_phys,
-n_pes, queue depth, fan-in widths) from the geometry, the batch size, the
+n_pes, queue depth, fan-in and fan-out widths) from the geometry, the
+batch size (which also picks the arbitration lookups, ``arb_fanout``), the
 lowered fault-entry count (padded to buckets, DESIGN.md §13), and the
 static ints (cycles, warmup, starvation_limit, trace-barrier semantics).
 Rates, seeds, localities, destination maps and fault drop masks are data.
@@ -30,7 +31,9 @@ Each sweep times its stages as ``repro.obs`` host spans
 ``.to_result``; every span of one group carries ``group=<k>``), and
 counts the arbitration passes its dispatches ran and needed
 (``sweep.arb_passes_run``, ``sweep.arb_passes_needed``) beside the
-point-cycles they simulated (``sweep.point_cycles``).
+point-cycles they simulated (``sweep.point_cycles``), of which those of
+dispatches that ran the fan-out lookups (``arb_fanout``) are
+``sweep.arb_fanout_point_cycles``.
 """
 from __future__ import annotations
 
@@ -52,6 +55,24 @@ from repro.core import traffic
 
 _AXIS = "points"   # the vmap axis of a batch
 
+# Smallest batch whose arbitration fixpoint reads each queue's target
+# through the static fan-out table (``cycle_step``'s ``arb_fanout``).  A
+# gather whose index differs per point costs about B x L one-element
+# fetches a pass; the fan-out form gathers L x Fo static indices, each
+# fetching a B-wide row (1-D, so dearer per index, at B = 1).  Measured
+# on a TPU v5e with ``bench/stage_split.py`` on the last B points of the
+# figs 15-17 grid at 1024 PEs, ``cycle.arbitrate`` us per point-cycle,
+# direct -> fan-out: ring_mesh B=1 5614 -> 15730, B=2 2325 -> 2568,
+# B=3 2059 -> 1817, B=4 1948 -> 1413; flat_mesh B=2 5513 -> 5355,
+# B=3 5066 -> 3849.  Ring-mesh loses at 2 points, both win from 3.
+ARB_FANOUT_MIN_BATCH = 3
+
+
+def arb_fanout(batch: int, backend: str) -> bool:
+    """Whether a ``batch``-point dispatch runs the fan-out lookups: XLA
+    backend batches of at least ``ARB_FANOUT_MIN_BATCH`` points."""
+    return backend == "xla" and batch >= ARB_FANOUT_MIN_BATCH
+
 
 @functools.partial(
     jax.jit, static_argnames=("cycles", "warmup", "starvation_limit",
@@ -65,12 +86,15 @@ def _run_batch(geom: sim.Geometry, points: sim.SweepPoint, *, cycles: int,
     geometry is broadcast (in_axes=None) so it is uploaded once.  Both
     backends vmap — the fused pallas kernel batches its traffic streams
     against the broadcast geometry.  The XLA scan learns the vmap axis,
-    so that it counts the arbitration passes the batch ran."""
+    so that it counts the arbitration passes the batch ran, and takes the
+    fan-out lookups when the batch is large enough (``arb_fanout``)."""
     run = functools.partial(sim._run_core, cycles=cycles, warmup=warmup,
                             starvation_limit=starvation_limit,
                             backend=backend, arb_iters=arb_iters,
                             strict_barrier=strict_barrier, watchdog=watchdog,
-                            batch_axis=_AXIS if backend == "xla" else None)
+                            batch_axis=_AXIS if backend == "xla" else None,
+                            arb_fanout=arb_fanout(points.seed.shape[0],
+                                                  backend))
     return jax.vmap(run, in_axes=(None, 0), axis_name=_AXIS)(geom, points)
 
 
@@ -89,9 +113,9 @@ def _static_key(geom: sim.Geometry, batch: int, trace_shape: tuple,
                 backend: str, strict_barrier: bool, watchdog: int,
                 arb_iters: int) -> tuple:
     return (geom.n_links, geom.n_phys, geom.n_pes, geom.depth,
-            geom.cand.shape, geom.intab.shape, batch, trace_shape,
-            fault_shape, cycles, warmup, starv, backend, strict_barrier,
-            watchdog, arb_iters)
+            geom.cand.shape, geom.intab.shape, geom.outtab.shape, batch,
+            trace_shape, fault_shape, cycles, warmup, starv, backend,
+            strict_barrier, watchdog, arb_iters)
 
 
 def _executable(geom: sim.Geometry, points: sim.SweepPoint, cycles: int,
@@ -167,7 +191,13 @@ def _dispatch(topo, cfgs, geom, idxs, points, exe, out):
             out[i] = sim._to_result(topo, cfgs[i], m_i)
     obs.add("sweep.arb_passes_run", int(metrics.arb_passes_run.sum()))
     obs.add("sweep.arb_passes_needed", int(metrics.arb_passes.sum()))
-    obs.add("sweep.point_cycles", cfgs[idxs[0]].cycles * len(idxs))
+    point_cycles = cfgs[idxs[0]].cycles * len(idxs)
+    obs.add("sweep.point_cycles", point_cycles)
+    # Added (0 too) on every dispatch: the counter's presence says the
+    # program can run the fan-out lookups.
+    obs.add("sweep.arb_fanout_point_cycles",
+            point_cycles if arb_fanout(len(idxs), cfgs[idxs[0]].backend)
+            else 0)
 
 
 def sweep(topo: topo_mod.Topology,

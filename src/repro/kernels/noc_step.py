@@ -2,7 +2,9 @@
 
 The scatter-free cycle step (DESIGN.md §4/§11) is three fused stages —
 route + arbitrate (gather + row-max over the structural fan-in candidate
-tables, then the grant/re-arbitrate feasibility fixpoint), queue
+tables, then the grant/re-arbitrate feasibility fixpoint, whose lookups
+at each queue's target a large batch reads through the static fan-out
+table instead of per-point indices), queue
 dequeue/enqueue on the packed int32 queue words, and integer Metrics
 accumulation.  Under the XLA backend each stage's intermediates round-trip
 through HBM between ``lax.scan`` iterations; here the whole cycle loop runs
@@ -56,7 +58,9 @@ class GeomArrays(NamedTuple):
     """Duck-typed view of ``sim.Geometry``'s device arrays.  The XLA scan
     backend passes the Geometry itself; the kernel rebuilds this view from
     values read out of its VMEM refs — ``cycle_step`` only touches these
-    attributes and ``.shape``, so either works."""
+    attributes and ``.shape``, so either works.  The fan-out tables
+    (``outtab``, ``outphys``, ``outcap``) are not here: the kernel runs
+    the direct arbitration lookups (``cycle_step``'s ``arb_fanout``)."""
 
     route: jax.Array        # [L+1, P] int16
     kind: jax.Array         # [L+1] int32
@@ -96,7 +100,8 @@ def initial_state(n_links: int, depth: int, *, n_pes: int = 0,
 def cycle_step(geom, state, cycle, inj, dst, fault_u=None, *, warmup: int,
                starvation_limit: int, arb_iters: int, trace=None,
                faults=None, strict_barrier: bool = False, watchdog: int = 0,
-               diagnostics: bool = False, batch_axis: str | None = None):
+               diagnostics: bool = False, batch_axis: str | None = None,
+               arb_fanout: bool = False):
     """One simulator cycle (route -> arbitrate -> move -> inject -> count).
 
     Pure function of VMEM-sized values; see ``core.sim``'s module docstring
@@ -144,6 +149,19 @@ def cycle_step(geom, state, cycle, inj, dst, fault_u=None, *, warmup: int,
     and ``ARB_PASSES_RUN`` adds that pass count (a ``pmax`` over the axis)
     where ``ARB_PASSES`` adds the point's own.  Without an axis both slots
     add the point's own count.
+
+    ``arb_fanout`` picks how the arbitration fixpoint reads a value at
+    each queue's target (its next queue's occupancy and capacity, its
+    output channel's best score, whether its target won).  The direct
+    form gathers at ``nxt_c``/``nxt_phys``, indices that differ per
+    point, which a batched TPU program lowers to one-element-at-a-time
+    gathers.  The fan-out form gathers the static rows of ``geom.outtab``
+    / ``outphys`` / ``outcap`` (``sim.Geometry``) once per cycle or pass
+    and keeps, with a one-hot mask, the column that is the target: more
+    elements, each a batch-wide row.  Both give the same bits.
+    ``core.sweep`` chooses it from the batch size; ``simulate`` and the
+    fused kernel (whose ``GeomArrays`` has no fan-out tables) take the
+    direct form.
     """
     if trace is None:
         q_pack, q_len, wait, m_scal, m_kind = state
@@ -201,8 +219,32 @@ def cycle_step(geom, state, cycle, inj, dst, fault_u=None, *, warmup: int,
         # the whole while_loop.
         cand_score = jnp.where(nxt_phys[geom.cand] == p_ids,
                                score[geom.cand], -1)   # [NP1, Fc]
-        ql_t = q_len[nxt_c]
-        cap_t = geom.cap[nxt_c]
+        if arb_fanout:
+            # Fan-out form: q's target is one of the queues leaving its
+            # destination node (``geom.outtab``), so "the value at q's
+            # target" is a gather of static rows plus a one-hot row
+            # reduction.  Exact for every contending queue (its target
+            # sits in its row once); the others are masked by ``active``.
+            sel = geom.outtab == nxt_c[:, None]          # [L+1, Fo]
+            sel_p = geom.outphys == nxt_phys[:, None]    # [L+1, Fo]
+            ql_t = jnp.max(jnp.where(sel, q_len[geom.outtab], 0), axis=1)
+            cap_t = jnp.max(jnp.where(sel, geom.outcap, 0), axis=1)
+
+            def at_channel(best):
+                return jnp.max(jnp.where(sel_p, best[geom.outphys], -1),
+                               axis=1)
+
+            def at_target(w):
+                return jnp.any(sel & w[geom.outtab], axis=1)
+        else:
+            ql_t = q_len[nxt_c]
+            cap_t = geom.cap[nxt_c]
+
+            def at_channel(best):
+                return best[nxt_phys]
+
+            def at_target(w):
+                return w[nxt_c]
 
         def select(active):
             # Scatter-free argmax per output channel: mask each channel's
@@ -211,13 +253,13 @@ def cycle_step(geom, state, cycle, inj, dst, fault_u=None, *, warmup: int,
             # (unique) score.
             best = jnp.max(jnp.where(active[geom.cand], cand_score, -1),
                            axis=1)
-            return active & (score == best[nxt_phys])
+            return active & (score == at_channel(best))
 
         def feasible(w):
             # A grant into a full queue is only feasible if that queue's
             # own head departs this cycle (lockstep / slotted-ring
             # semantics).
-            return (ql_t - w[nxt_c].astype(jnp.int32)) < cap_t
+            return (ql_t - at_target(w).astype(jnp.int32)) < cap_t
 
         # Grant-and-re-arbitrate fixpoint with early exit; residue past the
         # iteration cap is counted (not moved) so conservation stays

@@ -275,3 +275,23 @@ def test_compile_stats_and_cache_stats_are_registry_views():
     c1 = compile_cache.stats()
     assert (c1["hits"], c1["misses"]) == (c0["hits"] + 1, c0["misses"] + 1)
     assert c1["compile_s"] == pytest.approx(c0["compile_s"] + 0.5)
+
+
+def test_fanout_counter_counts_big_batches_only():
+    """``sweep.arb_fanout_point_cycles`` counts the point-cycles of the
+    dispatches that ran the fan-out lookups: a batch of at least
+    ``ARB_FANOUT_MIN_BATCH`` points, and not a 1-point dispatch."""
+    t = topology.build("ring_mesh", 16)
+    big = [_cfg(inj_rate=0.2 * (i + 1), seed=i)
+           for i in range(sweep.ARB_FANOUT_MIN_BATCH)]
+    one = sim.SimConfig(cycles=CYCLES // 2, warmup=WARMUP, inj_rate=0.5)
+    before = obs.snapshot()
+    sweep.sweep(t, big + [one])
+    after = obs.snapshot()
+    d = {k: after.get(k, 0) - before.get(k, 0)
+         for k in ("sweep.arb_fanout_point_cycles", "sweep.point_cycles")}
+    assert d["sweep.arb_fanout_point_cycles"] == len(big) * CYCLES
+    assert d["sweep.point_cycles"] == len(big) * CYCLES + CYCLES // 2
+    assert sweep.arb_fanout(len(big), "xla")
+    assert not sweep.arb_fanout(1, "xla")
+    assert not sweep.arb_fanout(len(big), "pallas")

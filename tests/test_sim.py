@@ -145,3 +145,62 @@ def test_patterns_are_fixed_permutations():
     perm = sim.pattern_destinations("bit_reversal", 256)
     assert sorted(perm.tolist()) == list(range(256))
     assert sim.pattern_destinations("uniform", 64) is None
+
+
+def _fanout_topology(case):
+    import dataclasses
+
+    from repro.core import morph
+    from repro.core import packet as pk
+    from repro.core.spec import TopologySpec
+    from repro.faults import sample_faults
+    if case == "morphed_ring_mesh":
+        # Morphed in place after the tables were built: they must stay
+        # valid for the new routes (a bypass and a switched-off ringlet).
+        t = topology.build_ring_mesh(64)
+        sim.build_geometry(t)
+        ctl = morph.MorphController(t)
+        ctl.apply(pk.MorphPacket(hl=1, ers=0,
+                                 link_states=(0, 0, 0, 1, 0, 0, 0, 0)),
+                  target=1)
+        ctl.apply(pk.MorphPacket(hl=1, ers=0,
+                                 link_states=(0, 0, 0, 0, 2, 0, 0, 0)),
+                  target=0)
+        return t
+    if case.startswith("repaired_"):
+        spec = TopologySpec(case.removeprefix("repaired_"), 64)
+        t = dataclasses.replace(spec, faults=sample_faults(
+            spec.build(), n_dead_links=3, seed=6)).build()
+        assert t.dead_queues is not None and t.dead_queues.any()
+        return t
+    name, n = case.rsplit("_", 1)
+    return topology.build(name, int(n))
+
+
+@pytest.mark.parametrize("case", [
+    f"{name}_{n}" for name in ("ring_mesh", "flat_mesh")
+    for n in (16, 64, 256, 1024)] + [
+    "morphed_ring_mesh", "repaired_ring_mesh", "repaired_flat_mesh"])
+def test_fanout_table_covers_every_route(case):
+    """Row q of ``Geometry.outtab`` lists every queue leaving q's
+    destination node, dead ones included, then pads with the dummy row:
+    so every live next hop of the route table is in its queue's row."""
+    t = _fanout_topology(case)
+    g = sim.build_geometry(t)
+    L = t.n_links
+    ot = np.asarray(g.outtab)
+    src, dst = t.link_src_node, t.link_dst_node
+    assert ot.shape[0] == L + 1 and np.all(ot[L] == L)
+    n_nodes = int(max(src.max(), dst.max())) + 1
+    leaving = np.bincount(src[src >= 0], minlength=n_nodes)
+    want = np.where(dst >= 0, leaving[np.clip(dst, 0, None)], 0)
+    real = ot[:L] != L
+    assert np.array_equal(real, np.arange(ot.shape[1]) < want[:, None])
+    assert np.all(src[ot[:L][real]]
+                  == np.broadcast_to(dst[:, None], real.shape)[real])
+    rt = t.route_table
+    live = rt >= 0
+    rows = np.arange(L, dtype=np.int64)[:, None] * (L + 1)
+    assert np.all(np.isin((rows + rt)[live], (rows + ot[:L])[real]))
+    assert np.array_equal(np.asarray(g.outphys), np.asarray(g.phys)[ot])
+    assert np.array_equal(np.asarray(g.outcap), np.asarray(g.cap)[ot])

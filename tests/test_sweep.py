@@ -114,3 +114,72 @@ def test_geometry_morph_aware():
     ctl.reset()
     restored = sim.simulate(t, cfg)
     assert restored == before
+
+
+def _fanout_batch(name, mode):
+    """Four points of one sweep group on a 16-PE fabric: statistical,
+    with faults injected unrepaired, or trace replays."""
+    from repro.faults import sample_faults
+    from repro.trace import Trace, TraceSpec
+    t = _topo(name, 16)
+    rates = ((0.3, 1), (0.7, 2), (1.0, 3), (1.0, 4))
+    if mode == "healthy":
+        return t, [sim.SimConfig(cycles=250, warmup=50, inj_rate=ir,
+                                 pattern=p, seed=s)
+                   for (ir, s), p in zip(rates, ("uniform", "transpose",
+                                                 "hotspot", "tornado"))]
+    if mode == "faults":
+        f = sample_faults(t, n_dead_links=2, n_transient=2, drop_p=0.3,
+                          onset=60, seed=6)
+        return t, [sim.SimConfig(cycles=250, warmup=50, inj_rate=ir,
+                                 seed=s, faults=f) for ir, s in rates]
+    return t, [sim.SimConfig(cycles=250, warmup=0, inj_rate=ir, seed=s,
+                             pattern=Trace(trace=TraceSpec(n_pes=16, phases=(
+                                 tuple((i, (i + 5) % 16, k) for i in range(16)),
+                                 tuple((i, (3 * i + 1) % 16, k)
+                                       for i in range(16))))))
+               for k, (ir, s) in enumerate(rates, start=2)]
+
+
+@pytest.mark.parametrize("mode", ["healthy", "faults", "trace"])
+@pytest.mark.parametrize("name", ["ring_mesh", "flat_mesh"])
+def test_fanout_batch_matches_direct_and_points(name, mode):
+    """A batch the sweep runs with the fan-out lookups gives every Metrics
+    field of the same batch run with the direct gathers, ``arb_passes_run``
+    included, and every per-point field of ``simulate``'s program and of
+    the Pallas interpret kernel (which run no batch, so their passes run
+    are their passes needed, at most the batch's)."""
+    import functools
+
+    import jax
+    t, cfgs = _fanout_batch(name, mode)
+    geom, ((key, idxs, points),) = sweep._grouped(t, cfgs)
+    assert sweep.arb_fanout(len(idxs), "xla")
+    cycles, warmup, starv, _, strict, watchdog = key
+    fan = sweep._run_batch(geom, points, cycles=cycles, warmup=warmup,
+                           starvation_limit=starv, strict_barrier=strict,
+                           watchdog=watchdog)
+    direct = jax.jit(jax.vmap(functools.partial(
+        sim._run_core, cycles=cycles, warmup=warmup, starvation_limit=starv,
+        strict_barrier=strict, watchdog=watchdog, batch_axis=sweep._AXIS,
+        arb_fanout=False), in_axes=(None, 0), axis_name=sweep._AXIS))(
+            geom, points)
+    fields = [f.name for f in dataclasses.fields(sim.Metrics)]
+    for f in fields:
+        np.testing.assert_array_equal(getattr(fan, f), getattr(direct, f), f)
+    assert np.all(fan.arb_passes_run >= fan.arb_passes)
+    for b in range(len(cfgs)):
+        point = jax.tree.map(lambda x, b=b: x[b], points)
+        for backend in ("xla", "pallas"):
+            one = sim._run_single(geom, point, cycles=cycles, warmup=warmup,
+                                  starvation_limit=starv, backend=backend,
+                                  strict_barrier=strict, watchdog=watchdog)
+            assert one.arb_passes_run == one.arb_passes
+            for f in fields:
+                if f != "arb_passes_run":
+                    np.testing.assert_array_equal(
+                        getattr(one, f), getattr(fan, f)[b], (backend, f))
+    if mode == "trace":
+        assert np.all(fan.phase_done[:, -1] >= 0)   # every replay completes
+    if mode == "faults":
+        assert np.all(fan.dropped > 0)
