@@ -1,0 +1,7 @@
+"""``step.arbitrate_us_per_point_cycle`` in the fault cells: the same reading,
+beside ``point_cycles_per_s.faults``, which has its own bound."""
+from ringbench import harness
+
+
+def read(ctx):
+    return harness.read_metric("step.arbitrate_us_per_point_cycle", ctx)

@@ -11,8 +11,9 @@ Bytes become single-flit packets of ``flit_bytes``, after dividing by a
 scale chosen so that the largest per-PE burst of any phase is
 ``normalize_flits`` flits; any positive volume is at least one flit.
 
-Each phase is a ``(dst, flits)`` pair of ``[P]`` arrays: PE ``s`` sends
-``flits[s]`` packets to ``dst[s]`` (0 flits: idle).
+Each phase is an int array of send-ordered records ``[R, 3]``, one row
+``(src, dst, flits)`` per active source, in source order: PE ``src`` sends
+``flits`` packets to ``dst``; a source with no row is idle.
 """
 from __future__ import annotations
 
@@ -58,7 +59,7 @@ def _phases(kind: str, groups, nbytes: float, algorithm: str):
 def schedule_phases(census: dict, n_pes: int, *, algorithm: str,
                     pod_size: int | None, normalize_flits: int,
                     flit_bytes: int) -> tuple[list, float]:
-    """``([(dst, flits), ...], scale)`` of one schedule."""
+    """``([records, ...], scale)`` of one schedule."""
     byte_phases = []
     for kind, nbytes in census["bytes_by_kind"].items():
         if kind not in KINDS:
@@ -68,12 +69,7 @@ def schedule_phases(census: dict, n_pes: int, *, algorithm: str,
                                    nbytes, algorithm)
     peak = max(b for ph in byte_phases for _, _, b in ph)
     scale = max(1.0, peak / (flit_bytes * normalize_flits))
-    out = []
-    for ph in byte_phases:
-        dst = np.zeros(n_pes, np.int32)
-        flits = np.zeros(n_pes, np.int32)
-        for s, d, b in ph:
-            dst[s] = d
-            flits[s] = max(1, math.ceil(b / (flit_bytes * scale))) if b else 0
-        out.append((dst, flits))
+    out = [np.array(sorted((s, d, max(1, math.ceil(b / (flit_bytes * scale))))
+                           for s, d, b in ph if b), np.int32).reshape(-1, 3)
+           for ph in byte_phases]
     return out, scale

@@ -17,7 +17,6 @@ window.
 from __future__ import annotations
 
 import glob
-import importlib.util
 import json
 import os
 import shutil
@@ -26,10 +25,13 @@ import tempfile
 import time
 import traceback
 
-from ringbench import check, reference, trace_reduce, workload
+from ringbench import (check, load_named, reference, stages, trace_reduce,
+                       workload)
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+BENCHMARK = os.path.join(ROOT, "BENCHMARK.json")
+TRAFFIC_DIR = os.path.join(BENCH_DIR, "traffic")
 WINDOW_SPAN = "bench.window"
 STEP_MODULE = "_run_batch"   # the sweep's batched executable
 
@@ -39,7 +41,7 @@ class Refused(Exception):
 
 
 def load_cell(name: str) -> dict:
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    with open(BENCHMARK) as f:
         bench = json.load(f)
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -48,8 +50,7 @@ def load_cell(name: str) -> dict:
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     with open(os.path.join(ROOT, entry["file"])) as f:
         config = json.load(f)
-    with open(os.path.join(BENCH_DIR, "traffic",
-                           cell["traffic"] + ".json")) as f:
+    with open(os.path.join(TRAFFIC_DIR, cell["traffic"] + ".json")) as f:
         mix = json.load(f)
 
     def mine(m):
@@ -60,12 +61,7 @@ def load_cell(name: str) -> dict:
 
 
 def read_metric(name: str, ctx: dict):
-    path = os.path.join(BENCH_DIR, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return load_named(os.path.join(BENCH_DIR, "metrics"), name).read(ctx)
 
 
 def devices(chips: int, platform: str):
@@ -94,23 +90,31 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
     compile_cache.enable()
     t0 = time.perf_counter()
     pts = workload.points(config, mix, seed)
-    exps = [program.experiment(config, p) for p in pts]
-    points_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation("bench.build"):
+    try:
+        exps = [program.experiment(config, p) for p in pts]
+        points_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
         topo = exps[0].topology.build()
         sim.build_geometry(topo)
         # The program walks a fault set's reachability on the host the
         # first time it reports on it, and caches the answer: walk it here.
         for e in exps:
             sim._fault_reachability(topo, e.sim_config().faults)
-    geometry_s = time.perf_counter() - t0
-    c0 = compile_cache.stats()["compile_s"]
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation("bench.precompile"):
+        geometry_s = time.perf_counter() - t0
+        c0 = compile_cache.stats()["compile_s"]
+        t0 = time.perf_counter()
         sweep.precompile([(topo, [e.sim_config() for e in exps])])
-    precompile_s = time.perf_counter() - t0
-    setup_compile_s = compile_cache.stats()["compile_s"] - c0
+        precompile_s = time.perf_counter() - t0
+        setup_compile_s = compile_cache.stats()["compile_s"] - c0
+    except Exception:
+        # The program refused the points or could not set up: no window,
+        # every point failed.
+        print(traceback.format_exc(), file=sys.stderr)
+        return _result(devs, n_devs, correct=False, attempted=0,
+                       failed=len(pts), metrics={}, memory=_peak(devs),
+                       checks={"mismatches": {"value": 0, "limit": 0},
+                               "failed_points": {"value": len(pts),
+                                                 "limit": 0}})
     setup_s = time.perf_counter() - t_start
 
     prof_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
@@ -124,8 +128,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
         while time.perf_counter() - w0 < seconds:
             c_start = time.perf_counter()
             try:
-                with jax.profiler.TraceAnnotation("bench.call"):
-                    calls.append(run_experiments(exps))
+                calls.append(run_experiments(exps))
             except Exception:
                 crashed = traceback.format_exc()
                 break
@@ -137,15 +140,19 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
                                                    "compile_s")}
     if trace:
         jax.profiler.stop_trace()
-    memory = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                 for d in devs)
+    memory = _peak(devs)
 
-    red = None
+    red, stage_s = None, {}
     if trace:
         files = glob.glob(os.path.join(prof_dir, "**", "*.xplane.pb"),
                           recursive=True)
-        red = trace_reduce.reduce(trace_reduce.events_from_file(files[0]),
-                                  window=WINDOW_SPAN, module_key=STEP_MODULE)
+        events = trace_reduce.events_from_file(files[0])
+        red = trace_reduce.reduce(events, window=WINDOW_SPAN,
+                                  module_key=STEP_MODULE)
+        stage_s = stages.stage_self_s(events, window=WINDOW_SPAN,
+                                      module_key=STEP_MODULE,
+                                      hlo_text=_step_hlo())
+        del events   # free the trace before the reference's check
         shutil.rmtree(prof_dir, ignore_errors=True)
 
     # The check, after the window: every call's counters against the
@@ -173,6 +180,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
                      "setup_compile_s": setup_compile_s},
         "spans": {"geometry_s": geometry_s},
         "trace": red,
+        "stages": stage_s,
         "fabric": reference.fabric_of(config),
         "device_kind": dev.device_kind,
     }
@@ -185,16 +193,11 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
               "failed_points": {"value": failed, "limit": 0}}
     correct = not crashed and bool(calls) and all(
         c["value"] <= c["limit"] for c in checks.values())
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": n_devs, "memory_peak_bytes": memory}
-    out = {"correct": correct, "attempted": len(calls) * len(pts),
-           "failed": failed, "metrics": metrics, "device": device}
+    busy = breakdown = None
     if red is not None:
-        device["busy_s"] = red["busy_s"]
-        device["window_s"] = red["window_s"]
-        out["breakdown"] = {
-            "device_ops": trace_reduce.top(red["op_self_s"]),
-            "idle_gaps": trace_reduce.top(red["idle_gaps_s"])}
+        busy = {"busy_s": red["busy_s"], "window_s": red["window_s"]}
+        breakdown = {"device_ops": trace_reduce.top(red["op_self_s"]),
+                     "idle_gaps": trace_reduce.top(red["idle_gaps_s"])}
     print(json.dumps({"call_s": call_s, "check_s": check_s,
                       "setup": {"to_devices_s": devices_s,
                                 "points_s": points_s,
@@ -202,6 +205,40 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, *,
                                 "precompile_s": precompile_s,
                                 "compile_s": setup_compile_s},
                       "window_compile": window_compile}), file=sys.stderr)
+    return _result(devs, n_devs, correct=correct,
+                   attempted=len(calls) * len(pts), failed=failed,
+                   metrics=metrics, checks=checks, memory=memory,
+                   busy=busy, breakdown=breakdown)
+
+
+def _step_hlo() -> str:
+    """The compiled text of the sweep's batched executables, which joins a
+    trace's op to its stage where the op's event names none; empty on a
+    program that keeps no such executables."""
+    from repro.core import sweep
+    return "\n".join(exe.as_text() or ""
+                     for exe in getattr(sweep, "_AOT", {}).values())
+
+
+def _peak(devs) -> int:
+    """Peak device memory in use on the fullest chip."""
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in devs)
+
+
+def _result(devs, n_devs: int, *, correct: bool, attempted: int,
+            failed: int, metrics: dict, checks: dict, memory: int,
+            busy: dict | None = None, breakdown: dict | None = None) -> dict:
+    """The result line; the numbers compared, each beside its limit, come
+    last there and as the last lines of standard error."""
+    dev = devs[0]
+    out = {"correct": correct, "attempted": attempted, "failed": failed,
+           "metrics": metrics,
+           "device": {"platform": dev.platform, "kind": dev.device_kind,
+                      "count": n_devs, "memory_peak_bytes": memory,
+                      **(busy or {})}}
+    if breakdown is not None:
+        out["breakdown"] = breakdown
     for k, c in checks.items():
         print(f"check {k} {c['value']} limit {c['limit']}", file=sys.stderr)
     out["checks"] = checks

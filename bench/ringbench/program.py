@@ -2,8 +2,6 @@
 counters of the program's ``Report``s."""
 from __future__ import annotations
 
-import numpy as np
-
 from repro.core import traffic as tf
 from repro.core.experiment import Budget, Experiment
 from repro.core.spec import TopologySpec
@@ -19,12 +17,11 @@ def topology(config: dict) -> TopologySpec:
 
 def experiment(config: dict, pt: dict) -> Experiment:
     if pt["phases"] is not None:
-        dst, flits = pt["phases"]
-        phases = tuple(tuple((int(s), int(dst[i, s]), int(flits[i, s]))
-                             for s in np.nonzero(flits[i])[0])
-                       for i in range(dst.shape[0]))
+        # The records in their order, neither merged nor split: a program
+        # that takes one destination per source per phase refuses a
+        # repeated source here.
         traffic = Trace(trace=TraceSpec(
-            n_pes=config["n_pes"], phases=phases,
+            n_pes=config["n_pes"], phases=pt["phases"],
             flit_bytes=pt["flit_bytes"], scale=pt["scale"],
             label=pt["schedule"]))
     else:

@@ -45,8 +45,6 @@ def counters(config: dict, point: dict,
     if phases is None:
         dst = traffic.destinations(d, p, point["pattern"],
                                    point["loc_ringlet"], point["loc_block"])
-    else:
-        phases = (np.asarray(phases[0]), np.asarray(phases[1]))
     return sim.simulate(
         f, cycles=point["cycles"], warmup=point["warmup"],
         starvation_limit=config["starvation_limit"],

@@ -22,10 +22,17 @@ Each cycle, in order:
 5. counters: from ``warmup`` on, delivered, offered, accepted, dropped,
    latency sum and hops moved; ``lost`` from cycle 0.
 
-Trace replay: each PE injects the current phase's flits to the phase's
-destination; the phase ends in the cycle in which the last of its flits
-is delivered or dropped, and the next phase's flits start the cycle after.
-A blocked injection waits, so trace traffic offers what it injects.
+Trace replay: each phase is a list of send-ordered records ``(src, dst,
+flits)``; a source may own several, and sends them in their order.  In
+phase ``i``, PE ``s`` offers its next flit to the destination of its first
+record whose running total of flits is above ``sent[s]``, the flits it has
+injected in the phase: all of a record's flits go before the next
+record's.  The injection draw and back-pressure gate it as in
+statistical traffic; a blocked injection waits, so trace traffic offers
+what it injects.  The phase ends in the cycle in which the last of its
+flits is delivered or dropped; ``sent`` and the phase's count of retired
+flits then reset, and the next phase's flits start the cycle after.  With
+one record per source this is a phase with one destination per source.
 """
 from __future__ import annotations
 
@@ -34,15 +41,34 @@ import numpy as np
 from ringbench.reference import fabric as fb
 
 
+def phase_tables(records, n_pes: int) -> tuple[np.ndarray, np.ndarray]:
+    """One phase's records ``[R, 3]`` as per-PE tables ``(dst, cum)`` of
+    shape ``[P, K]``, K the most records a source has: PE ``s``'s ``k``-th
+    record in send order is to ``dst[s, k]``, and ``cum[s, k]`` is the
+    running total of its flits through that record (padding repeats the
+    total)."""
+    rec = np.asarray(records, np.int64).reshape(-1, 3)
+    order = np.argsort(rec[:, 0], kind="stable")
+    src, d, fl = rec[order].T
+    k = np.arange(src.size) - np.searchsorted(src, src)
+    width = int(k.max()) + 1 if src.size else 1
+    dst = np.zeros((n_pes, width), np.int64)
+    flits = np.zeros((n_pes, width), np.int64)
+    dst[src, k] = d
+    flits[src, k] = fl
+    return dst, np.cumsum(flits, axis=1)
+
+
 def simulate(f: fb.Fabric, *, cycles: int, warmup: int,
              starvation_limit: int, arbitration_passes: int,
              inj: np.ndarray, dst: np.ndarray | None = None,
-             phases: tuple[np.ndarray, np.ndarray] | None = None,
+             phases: list | None = None,
              dead: np.ndarray | None = None) -> dict:
     """Counters of one point.  ``inj`` [cycles, P] bool, ``dst`` [cycles, P]
-    (statistical); ``phases`` = (dst, flits) [n_phases, P] (trace replay);
-    ``dead`` the ids of queues whose wire is dead: a flit granted onto it
-    leaves its queue and is dropped on the wire."""
+    (statistical); ``phases``, one ``[R, 3]`` array of ``(src, dst,
+    flits)`` records per phase (trace replay); ``dead`` the ids of queues
+    whose wire is dead: a flit granted onto it leaves its queue and is
+    dropped on the wire."""
     n_q, p = f.n_queues, f.n_pes
     finite = f.cap < fb.UNBOUNDED
     depth = int(f.cap[finite].max())
@@ -57,19 +83,23 @@ def simulate(f: fb.Fabric, *, cycles: int, warmup: int,
     c = dict.fromkeys(("delivered", "offered", "accepted", "dropped", "lost",
                        "lat_sum", "moved"), 0)
     if phases is not None:
-        ph_dst, ph_flits = phases
-        ph_total = ph_flits.sum(axis=1)
-        n_ph = ph_dst.shape[0]
+        tables = [phase_tables(r, p) for r in phases]
+        ph_total = [int(cum[:, -1].sum()) for _, cum in tables]
+        n_ph = len(tables)
         ph_idx, sent, credit = 0, np.zeros(p, np.int64), 0
         ph_done = [-1] * n_ph
+        pes = np.arange(p)
 
     for cycle in range(cycles):
         g = cycle >= warmup
         inj_row = inj[cycle]
         if phases is not None:
             cur = min(ph_idx, n_ph - 1)
-            inj_row = inj_row & (ph_idx < n_ph) & (ph_flits[cur] - sent > 0)
-            dst_row = ph_dst[cur]
+            ph_dst, cum = tables[cur]
+            inj_row = inj_row & (ph_idx < n_ph) & (cum[:, -1] - sent > 0)
+            k = np.minimum((cum <= sent[:, None]).sum(axis=1),
+                           cum.shape[1] - 1)
+            dst_row = ph_dst[pes, k]
         else:
             dst_row = dst[cycle]
 

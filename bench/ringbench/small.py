@@ -3,7 +3,9 @@ budgets.
 
 Every traffic parameter keeps its meaning; only sizes shrink (PE count,
 cycles, pod size, the number of dead links, drawn again for the small
-fabric), so a small run drives the same code paths as the cell."""
+fabric), so a small run drives the same code paths as the cell.  A
+generator's mix is cut through the configuration alone: the generator
+derives its sizes from the PE count."""
 from __future__ import annotations
 
 import copy
@@ -25,7 +27,8 @@ def shrink(spec: dict, n_pes: int = 16, cycles: int = 160) -> dict:
         for s in mix["collectives"]["schedules"]:
             if s["pod_size"]:
                 s["pod_size"] = 4
-        cycles *= 3   # every phase of every schedule must still complete
+    if "collectives" in mix or "generator" in mix:
+        cycles *= 3   # every phase of every trace must still complete
     mix["warmup"] = mix["warmup"] * cycles // mix["cycles"]
     mix["cycles"] = cycles
     chans = reference.fabric_of(cfg).fabric_channels()

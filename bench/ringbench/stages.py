@@ -151,3 +151,11 @@ def _label(lo: float, hi: float, mods: list[tr.Event], starts: list[float],
     if not inside:
         return NO_SPAN
     return min(inside, key=lambda e: e.dur_ns).name
+
+
+def us_per_point_cycle(ctx: dict, stage: str) -> float | None:
+    """A metric reader's value: microseconds of the step's device self
+    time in ``stage`` per simulated point-cycle of the traced window, or
+    None where the trace names no such stage."""
+    t, pc = ctx["stages"].get(stage), ctx["window"]["point_cycles"]
+    return None if t is None or not pc else 1e6 * t / pc

@@ -27,26 +27,20 @@ from ringbench import harness, registry, stages, trace_reduce  # noqa: E402
 def run(spec: dict, seed: int, seconds: float, *, t_start: float,
         platform: str = "tpu") -> dict:
     kept = {}
-    reduce = trace_reduce.reduce
+    split_fn = stages.stage_self_s
 
     def keep(events, **kw):
         kept["events"] = events
-        return reduce(events, **kw)
+        kept["split"] = split_fn(events, **kw)
+        return kept["split"]
 
-    trace_reduce.reduce = keep
+    stages.stage_self_s = keep
     try:
         out = harness.run(spec, seed, seconds, True, t_start=t_start,
                           platform=platform)
     finally:
-        trace_reduce.reduce = reduce
-    from repro.core import sweep
-    # The compiled text joins an op to its metadata where the trace's op
-    # events carry none.
-    hlo = "\n".join(exe.as_text() or "" for exe in sweep._AOT.values())
-    events = kept["events"]
-    split = stages.stage_self_s(events, window=harness.WINDOW_SPAN,
-                                module_key=harness.STEP_MODULE,
-                                hlo_text=hlo)
+        stages.stage_self_s = split_fn
+    events, split = kept["events"], kept["split"]
     c = registry.snapshot() or {}
     pc = c.get("sweep.point_cycles", 0)
     op = next((e for e in events if trace_reduce._is_device(e.plane)

@@ -104,6 +104,10 @@ def test_collective_phases_match_the_program_extractor(n_pes):
     assert [p["schedule"] for p in pts] == list(want)
     for p in pts:
         dst, flits = want[p["schedule"]].trace.arrays()
-        np.testing.assert_array_equal(p["phases"][0], dst)
-        np.testing.assert_array_equal(p["phases"][1], flits)
+        assert len(p["phases"]) == len(dst)
+        for r, d, f in zip(p["phases"], dst, flits):
+            # One row per active source, in source order.
+            np.testing.assert_array_equal(r[:, 0], np.nonzero(f)[0])
+            np.testing.assert_array_equal(r[:, 1], d[r[:, 0]])
+            np.testing.assert_array_equal(r[:, 2], f[r[:, 0]])
         assert p["scale"] == want[p["schedule"]].trace.scale

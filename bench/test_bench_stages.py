@@ -144,3 +144,24 @@ def test_readers_are_silent_without_the_registry(monkeypatch):
     ctx = {"window": {"seconds": 1.0, "calls": 1, "point_cycles": 900}}
     for name in NEW + ("experiment.host_s_per_call.faults",):
         assert harness.read_metric(name, ctx) is None, name
+
+
+STAGE_READERS = {
+    "step.arbitrate_us_per_point_cycle": "cycle.arbitrate",
+    "step.move_us_per_point_cycle": "cycle.move",
+    "step.phase_us_per_point_cycle": "cycle.phase",
+    "step.arbitrate_us_per_point_cycle.faults": "cycle.arbitrate",
+    "step.move_us_per_point_cycle.faults": "cycle.move",
+}
+
+
+@pytest.mark.parametrize("name", list(STAGE_READERS))
+def test_stage_readers(name):
+    """Microseconds of the stage per point-cycle from the run's stage
+    split; silent where the split lacks the stage (the CPU's trace has no
+    device plane, and a grid's step may run no phase op)."""
+    ctx = {"window": {"seconds": 1.0, "calls": 1, "point_cycles": 2000},
+           "stages": {STAGE_READERS[name]: 0.5, "unscoped": 0.1}}
+    assert harness.read_metric(name, ctx) == pytest.approx(250.0)
+    ctx["stages"] = {"unscoped": 0.1}
+    assert harness.read_metric(name, ctx) is None
