@@ -2,8 +2,8 @@
 generator file: the existing cells' points and the reference's counters
 are as they were before records, a source with several records replays
 as the reference's docstring states, and a new generator plus a new
-traffic file run end to end, or fail in set-up where the program refuses
-the records."""
+traffic file run end to end, and traffic with several records per
+source runs as the reference does or fails in set-up."""
 import hashlib
 import json
 import time
@@ -184,7 +184,29 @@ def traffics(config, mix, rng):
 
 
 @pytest.fixture
-def probe_cell(tmp_path, monkeypatch):
+def list_cell(tmp_path, monkeypatch):
+    """A copy of ``BENCHMARK.json`` that lists the cell
+    ``ring_mesh_1024.<traffic>``, as the cell's own entry would, in
+    ``point_cycles_per_s``'s cells too; the cell cut to 16 PEs."""
+    def make(traffic):
+        with open(harness.BENCHMARK) as f:
+            bench = json.load(f)
+        name = f"ring_mesh_1024.{traffic}"
+        bench["workloads"].append({
+            "name": name, "config": "ring_mesh_1024", "traffic": traffic,
+            "chips": 1, "why": "a test's cell"})
+        for m in bench["end_to_end"]:
+            if "workloads" in m and m["name"] == "point_cycles_per_s":
+                m["workloads"].append(name)
+        (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+        monkeypatch.setattr(harness, "BENCHMARK",
+                            str(tmp_path / "BENCHMARK.json"))
+        return small.shrink(harness.load_cell(name))
+    return make
+
+
+@pytest.fixture
+def probe_cell(tmp_path, monkeypatch, list_cell):
     """A cell whose traffic comes from a new generator file and a new
     traffic file, in a directory of their own; ``rows`` records per
     source per phase."""
@@ -197,20 +219,9 @@ def probe_cell(tmp_path, monkeypatch):
             "generator": "probe", "phases": 3, "rows": rows,
             "cycles": 400, "warmup": 0, "inj_rates": [1.0],
             "invariants": ["lost_zero", "conserved", "trace_completed"]}))
-        with open(harness.BENCHMARK) as f:
-            bench = json.load(f)
-        bench["workloads"].append({
-            "name": "ring_mesh_1024.probe", "config": "ring_mesh_1024",
-            "traffic": "probe", "chips": 1, "why": "a test's cell"})
-        for m in bench["end_to_end"]:
-            if "workloads" in m and m["name"] == "point_cycles_per_s":
-                m["workloads"].append("ring_mesh_1024.probe")
-        (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-        monkeypatch.setattr(harness, "BENCHMARK",
-                            str(tmp_path / "BENCHMARK.json"))
         monkeypatch.setattr(harness, "TRAFFIC_DIR", str(traffic))
         monkeypatch.setattr(workload, "GENERATOR_DIR", str(gen))
-        return small.shrink(harness.load_cell("ring_mesh_1024.probe"))
+        return list_cell("probe")
     return make
 
 
@@ -232,22 +243,62 @@ def test_a_generator_file_adds_a_cell(probe_cell):
     assert {"point_cycles_per_s", "setup_s"} <= set(out["metrics"])
 
 
-def test_repeated_sources_fail_in_setup(probe_cell, capsys):
-    """A program that takes one destination per source per phase refuses
-    the records in set-up: a result line, at once, with every point
-    failed and no window run."""
-    spec = probe_cell(rows=2)
-    t0 = time.perf_counter()
-    out = harness.run(spec, 2**31 + 23, 0.05, False, t_start=t0,
-                      platform="cpu")
-    assert time.perf_counter() - t0 < 60
+def assert_refused(out: dict, err: str, n_points: int, seconds: float):
+    """The result of a run whose set-up raised: a result line, at once,
+    with every point failed, no window run and the traceback on standard
+    error."""
+    assert seconds < 60
     assert out["correct"] is False
-    assert out["attempted"] == 0 and out["failed"] == 2
+    assert out["attempted"] == 0 and out["failed"] == n_points
     assert out["metrics"] == {}
     assert list(out)[:5] == ["correct", "attempted", "failed", "metrics",
                              "device"]
-    assert out["checks"]["failed_points"] == {"value": 2, "limit": 0}
-    err = capsys.readouterr().err
-    assert "Traceback" in err and "appears twice" in err
-    assert err.rstrip().splitlines()[-1] == "check failed_points 2 limit 0"
+    assert out["checks"]["failed_points"] == {"value": n_points, "limit": 0}
+    assert "Traceback" in err
+    assert err.rstrip().splitlines()[-1] == \
+        f"check failed_points {n_points} limit 0"
     json.dumps(out)
+
+
+def test_a_setup_refusal_fails_every_point(probe_cell, capsys, monkeypatch):
+    """A program that refuses the points in set-up fails every point; the
+    refusal is planted here, so the test rests on no rule of the
+    program's."""
+    from ringbench import program
+
+    def refuse(config, pt):
+        raise ValueError("the program refuses this point")
+
+    spec = probe_cell(rows=1)
+    monkeypatch.setattr(program, "experiment", refuse)
+    t0 = time.perf_counter()
+    out = harness.run(spec, 2**31 + 23, 0.05, False, t_start=t0,
+                      platform="cpu")
+    err = capsys.readouterr().err
+    assert_refused(out, err, 2, time.perf_counter() - t0)
+    assert "the program refuses this point" in err
+
+
+@pytest.mark.parametrize("traffic", ["probe", "moe_a2a"])
+def test_several_records_per_source_run_or_fail_in_setup(
+        traffic, probe_cell, list_cell, capsys):
+    """Traffic with several records per source in a phase (the probe with
+    two rows, and moe_a2a at 16 PEs) passes in one of two ways: the
+    program replays it as the reference does, or it refuses it in set-up.
+    A program that accepts the records and replays them otherwise fails
+    the comparison, and this test with it."""
+    spec = probe_cell(rows=2) if traffic == "probe" else list_cell(traffic)
+    seed = 2**31 + 23
+    pts = workload.points(spec["config"], spec["mix"], seed)
+    assert any(len(np.unique(r[:, 0])) < len(r)
+               for p in pts for r in p["phases"])
+    t0 = time.perf_counter()
+    out = harness.run(spec, seed, 0.05, False, t_start=t0, platform="cpu")
+    seconds = time.perf_counter() - t0
+    err = capsys.readouterr().err
+    if out["attempted"] == 0:
+        assert_refused(out, err, len(pts), seconds)
+    else:
+        assert out["checks"] == {"mismatches": {"value": 0, "limit": 0},
+                                 "failed_points": {"value": 0, "limit": 0}}
+        assert out["correct"] is True and out["failed"] == 0
