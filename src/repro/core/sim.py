@@ -285,7 +285,8 @@ class SweepPoint:
     # destination map and flit counts.  Statistical points carry the empty
     # [0, n_pes] shape, which is static, so trace-ness (and the phase
     # count) is part of the compile key while the tables stay traced data —
-    # grids of different traces on one topology share one executable.
+    # grids of different traces on one topology share one executable
+    # (``core.sweep`` pads shorter tables with zero-flit phases).
     ph_dst: jax.Array
     ph_flits: jax.Array
     # Fault injection (repro.faults): lowered per-queue drop-mask entries
@@ -293,7 +294,8 @@ class SweepPoint:
     # empty [0] shape; faulted points are padded to a small static bucket,
     # so the fault *shape* joins the compile key while fault identity
     # (which links, what rates, what seeds) stays traced data — whole
-    # resilience grids vmap through one executable.
+    # resilience grids vmap through one executable (``core.sweep`` pads
+    # every point of a batch to its largest bucket).
     fault_links: jax.Array   # [F] int32 queue ids (pad -> n_links)
     fault_drop_p: jax.Array  # [F] f32 (pad -> 0.0)
     fault_onset: jax.Array   # [F] int32
@@ -567,9 +569,13 @@ def _run_core(geom: Geometry, point: SweepPoint, *, cycles: int, warmup: int,
     # scan: peer indices are static, all randomness is drawn in five large
     # vectorized calls instead of per-cycle splits) ----------------------
     # Fault entries ride the point as traced data; their [F] shape is the
-    # static "fault shape".  Healthy points keep the historical 5-way key
-    # split, so healthy random streams are bit-identical with or without
-    # the fault machinery compiled in.
+    # static "fault shape".  A point with fault entries splits its key 6
+    # ways and a healthy one 5 ways; the first five keys of a 6-way split
+    # are the 5-way split's (threefry, partitionable), which is what lets
+    # healthy and faulted points share a dispatch: a healthy point padded
+    # with entries that never fire draws its own streams.  Entry j's drop
+    # chances are row j of an [F, cycles] draw, the same for every F, so a
+    # point padded to a larger fault bucket keeps them too.
     n_faults = int(point.fault_links.shape[0])
     with jax.named_scope("point.traffic"):
         pes = jnp.arange(P, dtype=jnp.int32)
@@ -581,7 +587,7 @@ def _run_core(geom: Geometry, point: SweepPoint, *, cycles: int, warmup: int,
         if n_faults:
             k_inj, k_dst, k_loc, k_ring, k_blk, k_flt = jax.random.split(
                 key, 6)
-            fu_s = jax.random.uniform(k_flt, (cycles, n_faults))
+            fu_s = jax.random.uniform(k_flt, (n_faults, cycles)).T
             faults = (point.fault_links, point.fault_drop_p,
                       point.fault_onset)
         else:
@@ -742,7 +748,10 @@ def _to_result(topo: topo_mod.Topology, cfg: SimConfig,
         throughput=delivered / mc,
         flit_hops_per_cycle=int(m.moved) / mc,
         per_pe_throughput=delivered / mc / topo.n_pes,
-        phase_done=tuple(int(d) for d in np.asarray(m.phase_done)),
+        # A sweep pads a point's phase table to its group's longest; the
+        # padded phases are not the point's.
+        phase_done=tuple(int(d) for d in np.asarray(m.phase_done)[
+            :traffic.resolve(cfg.pattern).n_trace_phases]),
         reachability=_fault_reachability(topo, cfg.faults),
         stall_unretired=int(m.stall_unretired),
         arb_passes=int(m.arb_passes),

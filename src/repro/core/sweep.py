@@ -11,12 +11,15 @@ compiled program and returns all results from one device execution.
 Compile-cache key (DESIGN.md §4): array *shapes* only — (n_links, n_phys,
 n_pes, queue depth, fan-in and fan-out widths) from the geometry, the
 batch size (which also picks the arbitration lookups, ``arb_fanout``), the
-lowered fault-entry count (padded to buckets, DESIGN.md §13), and the
-static ints (cycles, warmup, starvation_limit, trace-barrier semantics).
-Rates, seeds, localities, destination maps and fault drop masks are data.
-``sweep()`` groups its configs by the static key internally, so
-mixed-budget batches still compile once per distinct budget, and results
-always come back in input order.
+batch's trace phase count and lowered fault-entry count, and the static
+ints (cycles, warmup, starvation_limit, trace-barrier semantics).  Rates,
+seeds, localities, destination maps and fault drop masks are data.
+``sweep()`` groups its configs by the static ints and trace-ness, so
+mixed-budget batches still compile once per distinct budget; points of a
+group with fewer trace phases or a smaller fault bucket than the group's
+largest are padded to it with entries that never act (DESIGN.md §12-13),
+so one call of mixed trace lengths or of healthy and faulted points is
+one dispatch.  Results always come back in input order.
 
     topo = topology.build_ring_mesh(256)
     cfgs = sweep.grid(inj_rates=(0.25, 0.5, 1.0),
@@ -33,7 +36,9 @@ counts the arbitration passes its dispatches ran and needed
 (``sweep.arb_passes_run``, ``sweep.arb_passes_needed``) beside the
 point-cycles they simulated (``sweep.point_cycles``), of which those of
 dispatches that ran the fan-out lookups (``arb_fanout``) are
-``sweep.arb_fanout_point_cycles``.
+``sweep.arb_fanout_point_cycles``.  It counts its dispatches
+(``sweep.dispatches``) and the points among them whose phase tables or
+fault entries were padded (``sweep.padded_points``).
 """
 from __future__ import annotations
 
@@ -150,13 +155,39 @@ def _executable(geom: sim.Geometry, points: sim.SweepPoint, cycles: int,
 
 def _stack_points(cfgs: Sequence[sim.SimConfig],
                   topo: topo_mod.Topology) -> sim.SweepPoint:
+    """The points of ``cfgs`` stacked on a leading batch axis.  Phase
+    tables get trailing rows of zero flits and fault entries trailing
+    ``(n_links, 0.0, 0)`` entries, up to the group's largest: a zero-flit
+    phase completes the cycle after the one before it, and a pad entry
+    never fires, so every counter of a padded point is its own."""
     pts = [sim.make_point(c, topo.n_pes, topo) for c in cfgs]
+    n_ph = max(p.ph_dst.shape[0] for p in pts)
+    n_f = max(p.fault_links.shape[0] for p in pts)
+
+    def pad(x, n, value):
+        if x.shape[0] == n:
+            return x
+        return np.pad(x, [(0, n - x.shape[0])] + [(0, 0)] * (x.ndim - 1),
+                      constant_values=value)
+
+    pts = [dataclasses.replace(
+        p, ph_dst=pad(p.ph_dst, n_ph, 0), ph_flits=pad(p.ph_flits, n_ph, 0),
+        fault_links=pad(p.fault_links, n_f, topo.n_links),
+        fault_drop_p=pad(p.fault_drop_p, n_f, 0.0),
+        fault_onset=pad(p.fault_onset, n_f, 0)) for p in pts]
     return jax.tree.map(lambda *xs: np.stack(xs), *pts)
 
 
+def _own_shape(cfg: sim.SimConfig, topo: topo_mod.Topology
+               ) -> tuple[int, int]:
+    """A point's own (trace phase count, lowered fault count), before the
+    group pads it."""
+    return (traffic.resolve(cfg.pattern).n_trace_phases,
+            cfg.faults.n_lowered(topo) if cfg.faults else 0)
+
+
 # How many leading entries of a group key are _executable statics; the
-# remainder (trace phase count, lowered fault count) are array *shapes*
-# that only gate which points may stack together.
+# last (trace-ness) only gates which points may stack together.
 _N_EXE_STATICS = 6
 
 
@@ -166,17 +197,15 @@ def _grouped(topo: topo_mod.Topology, cfgs: Sequence[sim.SimConfig]):
         geom = sim.build_geometry(topo)
         groups: dict[tuple, list[int]] = {}
         for i, c in enumerate(cfgs):
-            # The trace phase count and the lowered fault count are array
-            # *shapes*, so points can only stack (and share an executable)
-            # with equal counts; statistical points all have
-            # n_trace_phases == 0, healthy points n_faults == 0, and fault
-            # lowering pads to bucket sizes so nearby fault counts
-            # coincide.
-            n_phases = traffic.resolve(c.pattern).n_trace_phases
-            n_faults = c.faults.n_lowered(topo) if c.faults else 0
+            # Trace-ness is a static branch of the step, so statistical
+            # and trace points never share a dispatch.  The phase count
+            # and the lowered fault count are array shapes too, but
+            # ``_stack_points`` pads them to the group's largest, so they
+            # do not split a group.
             groups.setdefault((c.cycles, c.warmup, c.starvation_limit,
                                c.backend, c.strict_barrier, c.watchdog,
-                               n_phases, n_faults), []).append(i)
+                               traffic.resolve(c.pattern).is_trace),
+                              []).append(i)
         return geom, [(key[:_N_EXE_STATICS], idxs,
                        _stack_points([cfgs[i] for i in idxs], topo))
                       for key, idxs in groups.items()]
@@ -189,6 +218,10 @@ def _dispatch(topo, cfgs, geom, idxs, points, exe, out):
         for b, i in enumerate(idxs):
             m_i = jax.tree.map(lambda x: x[b], metrics)
             out[i] = sim._to_result(topo, cfgs[i], m_i)
+    obs.add("sweep.dispatches")
+    shape = (points.ph_dst.shape[1], points.fault_links.shape[1])
+    obs.add("sweep.padded_points",
+            sum(_own_shape(cfgs[i], topo) != shape for i in idxs))
     obs.add("sweep.arb_passes_run", int(metrics.arb_passes_run.sum()))
     obs.add("sweep.arb_passes_needed", int(metrics.arb_passes.sum()))
     point_cycles = cfgs[idxs[0]].cycles * len(idxs)
@@ -205,9 +238,12 @@ def sweep(topo: topo_mod.Topology,
           verify: bool = False) -> list[sim.SimResult]:
     """Run every config on ``topo`` in batched device executions.
 
-    Configs sharing (cycles, warmup, starvation_limit) — the static compile
-    key — are executed as one vmapped dispatch; results return in the order
-    of ``cfgs``.  Metrics are bit-identical to per-point ``sim.simulate``.
+    Configs sharing (cycles, warmup, starvation_limit, backend,
+    strict_barrier, watchdog) — the static compile key — and trace-ness
+    are executed as one vmapped dispatch, whatever their trace phase
+    counts and fault buckets (the smaller are padded); results return in
+    the order of ``cfgs``.  Metrics are bit-identical to per-point
+    ``sim.simulate``.
 
     ``verify=True`` statically certifies the fabric first (deadlock
     freedom + route liveness, ``analysis.fabric``) and raises

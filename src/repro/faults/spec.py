@@ -37,7 +37,8 @@ A ``FaultSpec`` is *where you attach it*:
 
 Lowered entry counts are padded to a small static bucket (``_PAD_FLOOR``
 minimum, then powers of two) so nearby fault counts share one compile
-key — the "fault shape" that joins ``core.sweep``'s grouping.
+key — the "fault shape"; ``core.sweep`` pads every point of a batch to
+its largest bucket with the same never-firing entries.
 """
 from __future__ import annotations
 
@@ -185,8 +186,7 @@ class FaultSpec:
         return links, drop_p, onset
 
     def n_lowered(self, topo: topo_mod.Topology) -> int:
-        """Padded entry count — the static "fault shape" that joins the
-        sweep compile key."""
+        """Padded entry count — the static "fault shape" of the point."""
         n = int(self.dead_queue_mask(topo).sum())
         n += sum(int((topo.link_phys == t.link).sum())
                  for t in self.transient)

@@ -278,9 +278,9 @@ def test_watchdog_does_not_fire_on_healthy_trace():
 # Batched resilience sweeps
 # ---------------------------------------------------------------------------
 def test_fault_sweep_batches_and_matches_simulate():
-    """A fault grid must vmap: scenarios in the same pad bucket share one
-    executable with the healthy points compiling separately, and every
-    batched row must equal its per-point oracle bit for bit."""
+    """A fault grid must vmap: the scenarios and the healthy points, padded
+    to the one fault bucket, share one executable, and every batched row
+    must equal its per-point oracle bit for bit."""
     topo = _SPEC.build()
     sweep.reset_caches()
     cfgs = sweep.grid(inj_rates=(0.2, 0.4), seeds=(0,), cycles=300,
@@ -289,7 +289,7 @@ def test_fault_sweep_batches_and_matches_simulate():
                               _faults(n_dead=4, seed=1),
                               _faults(n_transient=2, seed=2)))
     rs = sweep.sweep(topo, cfgs)
-    assert sweep.compile_stats()["batch_xla_compiles"] == 2
+    assert sweep.compile_stats()["batch_xla_compiles"] == 1
     for cfg, rb in zip(cfgs, rs):
         assert rb == sim.simulate(topo, cfg)
 

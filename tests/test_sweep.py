@@ -183,3 +183,83 @@ def test_fanout_batch_matches_direct_and_points(name, mode):
         assert np.all(fan.phase_done[:, -1] >= 0)   # every replay completes
     if mode == "faults":
         assert np.all(fan.dropped > 0)
+
+
+def _trace(*phases):
+    from repro import trace as tr
+    return tr.from_records(16, [list(p) for p in phases])
+
+
+def _padded_calls():
+    """Calls whose points differ only in trace phase count or in fault
+    bucket, on a 16-PE ring-mesh, with the points each call pads."""
+    from repro.faults import FaultSpec, LinkFault, fabric_channels
+    t = _topo("ring_mesh", 16)
+    two = _trace([(i, (i + 5) % 16, 2) for i in range(16)],
+                 [(i, (3 * i + 1) % 16, 2) for i in range(16)])
+    three = _trace([(i, (i + 7) % 16, 1) for i in range(16)],
+                   [(i, (i + 1) % 16, 3) for i in range(16)],
+                   [(i, (5 * i + 2) % 16, 2) for i in range(16)])
+    lengths = [sim.SimConfig(cycles=300, warmup=0, inj_rate=0.8, seed=s,
+                             pattern=p) for s, p in ((1, two), (2, three))]
+    # PE 0 -> 8 crosses router 0, which is dead: the watchdog ends the
+    # 2-phase replay in its first phase; the 3-phase replay is healthy.
+    stall = [sim.SimConfig(cycles=400, warmup=0, inj_rate=1.0, seed=s,
+                           pattern=p, strict_barrier=True, watchdog=48,
+                           faults=f)
+             for s, p, f in ((3, _trace([(0, 8, 4)], [(0, 1, 4)]),
+                              FaultSpec(dead_routers=(0,))),
+                             (4, _trace([(0, 1, 4)], [(2, 9, 3)],
+                                        [(5, 12, 2)]), None))]
+    # Transient faults in bucket 16 padded to 32: their drop chances must
+    # not move with the bucket.
+    chans = [int(c) for c in fabric_channels(t)]
+    buckets = [sim.SimConfig(cycles=300, warmup=0, inj_rate=0.4, seed=s,
+                             faults=f)
+               for s, f in ((5, None),
+                            (6, FaultSpec(transient=tuple(
+                                LinkFault(link=c, drop_p=0.4, onset=20)
+                                for c in chans[:3]))),
+                            (7, FaultSpec(dead_links=tuple(chans[4:14]))))]
+    return t, {"trace_lengths": (lengths, 1), "strict_watchdog": (stall, 2),
+               "fault_buckets": (buckets, 2)}
+
+
+@pytest.mark.parametrize("case", ["trace_lengths", "strict_watchdog",
+                                  "fault_buckets"])
+def test_unequal_shapes_stack_into_one_dispatch(case):
+    """Points of one call with unequal trace phase counts or fault buckets
+    are padded to one shape: one compile, one dispatch, and every row
+    equal to ``simulate``'s, with the point's own phases."""
+    from repro import obs
+    t, calls = _padded_calls()
+    cfgs, n_padded = calls[case]
+    shapes = {sweep._own_shape(c, t) for c in cfgs}
+    assert len(shapes) == len(cfgs)     # no two points share a shape
+    sweep.reset_caches()
+    rs = sweep.sweep(t, cfgs)
+    counters = obs.snapshot()
+    assert sweep.compile_stats()["batch_xla_compiles"] == 1
+    assert counters["sweep.dispatches"] == 1
+    assert counters["sweep.padded_points"] == n_padded
+    for cfg, r in zip(cfgs, rs):
+        assert r == sim.simulate(t, cfg), cfg
+        assert len(r.phase_done) == sweep._own_shape(cfg, t)[0]
+    if case == "strict_watchdog":
+        assert rs[0].stalled_phase == 0 and rs[0].phase_done[1] == -1
+        assert rs[1].trace_completed
+    if case == "fault_buckets":
+        assert [sweep._own_shape(c, t)[1] for c in cfgs] == [0, 16, 32]
+        assert rs[1].dropped > 0 and rs[2].dropped > 0
+
+
+def test_six_way_split_starts_with_the_five_way_split():
+    """A healthy point batched with faulted ones splits its key 6 ways,
+    and depends on the first five keys being the 5-way split's for its
+    random streams to be its own."""
+    import jax
+    for seed in (0, 1, 12345, 2 ** 31 - 1, -7):
+        key = jax.random.PRNGKey(seed)
+        np.testing.assert_array_equal(
+            np.asarray(jax.random.split(key, 6))[:5],
+            np.asarray(jax.random.split(key, 5)))
